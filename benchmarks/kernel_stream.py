@@ -1,6 +1,6 @@
 """Streaming-compaction perf smoke: cap sweep across the old VMEM ceiling.
 
-The resident ``block_compact`` keeps its whole ``[C, cap + SUB]`` output in
+The resident ``block_compact`` keeps its whole padded ``[C, cap]`` output in
 VMEM, so its capacity tops out at :data:`repro.kernels.ops.VMEM_BUDGET_BYTES`
 (~512K rows at 4 columns).  The streaming variant keeps the output in HBM
 and emits tiles by double-buffered DMA — capacity becomes memory-bounded.

@@ -85,6 +85,7 @@ def merge_figure(fig: str, out_dir: Path, platforms) -> int:
 
 def main(argv=None) -> int:
     from repro.core import config as config_mod
+    from repro.core.device import enable_compile_cache
 
     p = argparse.ArgumentParser(prog="benchmarks.run")
     p.add_argument("--only", nargs="*", default=None, help="figure ids to run")
@@ -98,6 +99,7 @@ def main(argv=None) -> int:
     p.add_argument("--out", default=str(RESULTS))
     p.add_argument("--list", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     if args.list:
         for fig, box in FIGURES.items():

@@ -4,8 +4,9 @@ Three checks, all on CPU (interpret mode) so CI can run them:
 
   1. **Scan-sharing oracle** — for every query, a micro-batch of requests
      with different predicate constants through the multi-program kernel
-     must be BYTE-IDENTICAL to serial per-request execution (both pallas
-     and ref paths).  Any byte of drift fails the job.
+     must equal serial per-request execution (both pallas and ref paths):
+     counts exactly, float sums within ``FLOAT_RTOL`` (interpret mode lets
+     XLA reorder the additions inside a block's dot by an ulp or so).
   2. **Shed-free below saturation** — measure each (query, platform)
      point's closed-loop saturation QPS, then offer a fixed-rate open-loop
      load at a fraction of it for ``--duration`` seconds; admission
@@ -30,10 +31,11 @@ import numpy as np
 
 QUERIES = ("q1", "q6", "q12")
 ROWS = 6_000  # scale 0.001: small enough for interpret-mode CI, real kernels
+FLOAT_RTOL = 1e-6
 
 
 def check_scan_sharing() -> list[str]:
-    """Byte-diff micro-batched vs serial fused-query results."""
+    """Diff micro-batched vs serial fused-query results."""
     from repro.engine import datagen, queries
     from repro.runtime.loadgen import sample_params
 
@@ -53,13 +55,18 @@ def check_scan_sharing() -> list[str]:
                     plans[qname], params, use_pallas=use_pallas
                 )
                 for k in want:
-                    if not np.array_equal(np.asarray(want[k]), np.asarray(got[k])):
+                    w, g = np.asarray(want[k]), np.asarray(got[k])
+                    same = (
+                        np.array_equal(w, g) if k in queries.COUNT_KEYS
+                        else np.allclose(g, w, rtol=FLOAT_RTOL, atol=0)
+                    )
+                    if not same:
                         failures.append(
                             f"{qname}[{i}] pallas={use_pallas}: {k} differs "
                             f"(batched != serial)"
                         )
         mode = "pallas+ref"
-        print(f"# {qname}: {len(param_list)}-request micro-batch byte-equal serial ({mode})")
+        print(f"# {qname}: {len(param_list)}-request micro-batch equals serial ({mode})")
     return failures
 
 

@@ -75,6 +75,7 @@ from repro.core import cache as cache_mod
 from repro.core import registry, report
 from repro.core.box import Box
 from repro.core.cost import CostModel
+from repro.core.device import check_children_can_use_device
 from repro.core.metrics import compute_metrics
 from repro.core.platform import Platform, resolve
 from repro.core.scheduler import (
@@ -1144,6 +1145,9 @@ class SweepExecutor:
                     if self.pool == "process":
                         import multiprocessing
 
+                        check_children_can_use_device(
+                            "--pool process", "use --pool thread"
+                        )
                         proc_pool = ProcessPoolExecutor(
                             max_workers=self.workers,
                             mp_context=multiprocessing.get_context("spawn"),
@@ -1263,6 +1267,7 @@ class SweepExecutor:
                 misses.append(unit)
         if not misses:
             return
+        check_children_can_use_device("--pool process", "use --pool thread")
         ctx = multiprocessing.get_context("spawn")
         with ProcessPoolExecutor(max_workers=self.workers, mp_context=ctx) as pool:
             pairs = [

@@ -85,6 +85,7 @@ from typing import Any, Sequence
 
 from repro.core import registry
 from repro.core.cache import EWMA_ALPHA
+from repro.core.device import check_children_can_use_device
 from repro.core.metrics import Samples
 
 CONNECT_TIMEOUT_S = 10.0
@@ -1089,6 +1090,11 @@ class LocalWorker:
     def __enter__(self) -> "LocalWorker":
         import queue
 
+        check_children_can_use_device(
+            "LocalWorker",
+            "start the worker on its own (python -m repro.core.remote worker) "
+            "before any process opens the chip",
+        )
         cmd = [
             sys.executable, "-m", "repro.core.remote", "worker",
             "--port", "0", "--capacity", str(self.capacity),

@@ -52,6 +52,7 @@ from repro.core import config as config_mod
 from repro.core import registry, report
 from repro.core.box import Box
 from repro.core.cache import ResultCache
+from repro.core.device import enable_compile_cache
 from repro.core.executor import SweepExecutor, SweepStats
 from repro.core.shard import ShardSpec
 from repro.core.task import TestResult
@@ -196,6 +197,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--list-tasks", action="store_true")
     p.add_argument("--list-platforms", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
     args.box = args.box_opt or args.box_pos
 
     if args.list_tasks:

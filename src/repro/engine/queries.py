@@ -310,6 +310,9 @@ def q12_fused(
 
 QUERIES = {"q1": q1, "q6": q6, "q12": q12}
 FUSED_QUERIES = {"q1": q1_fused, "q6": q6_fused, "q12": q12_fused}
+#: Result keys that are row counts: every plan must agree on them exactly,
+#: while float sums may differ in the order of their additions.
+COUNT_KEYS = ("count", "rows", "high_line_count", "low_line_count")
 
 
 # ---------------------------------------------------------------------------
@@ -396,9 +399,11 @@ def fused_query_batch(
     """Scan sharing: N same-shape requests, ONE kernel pass over the data.
 
     Each request's constants become one slot of the batched SMEM program
-    tables; results demultiplex per request and are bit-equal to
-    ``fused_query_serial`` on the same constants (the kernel's per-program
-    block-accumulation order is identical to the single-program path).
+    tables; results demultiplex per request and equal
+    ``fused_query_serial`` on the same constants: counts exactly, float
+    sums within a few ulps (the per-program block order is the
+    single-program path's; the additions inside one block's dot may be
+    ordered differently).
     """
     consts = [plan.program(p) for p in param_list]
     pred_consts = jnp.stack([c[0] for c in consts])

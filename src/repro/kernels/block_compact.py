@@ -3,42 +3,45 @@
 ``engine.ops.compact`` materializes qualifying rows with ``jnp.nonzero`` +
 gather: one full pass to build the index vector in HBM, then one gather pass
 per column.  The fused plan is one pass: each input block computes its mask
-count and in-block prefix offsets (exclusive cumsum of the mask), converts
+count and in-block prefix offsets (exclusive prefix sum of the mask), converts
 the offsets into a scatter permutation, and writes its qualifying rows
 densely into a capacity-bounded output buffer at the running global offset.
 
 Mechanics per SUB-row sub-tile (SUB = 512, keeps the permutation matrix at
 SUB x SUB f32 = 1 MB):
 
-  * ``pos = cumsum(mask) - mask`` — each qualifying row's slot among the
-    sub-tile's qualifiers;
-  * scatter-as-matmul: ``P[r, j] = mask[r] & (pos[r] == j)``, and
-    ``cols_sub [C, SUB] @ P [SUB, SUB]`` lands every qualifying row at its
-    slot (MXU work instead of an unsupported vector scatter);
-  * the compacted sub-tile is stored at ``out[:, base : base + SUB]`` where
+  * ``pos`` = exclusive prefix sum of the mask (log-step lane rotations) —
+    each qualifying row's slot among the sub-tile's qualifiers;
+  * scatter-as-matmul: ``P[j, r] = mask[r] & (pos[r] == j)``, and
+    ``cols_sub [C, SUB] @ P^T`` lands every qualifying row at its slot (MXU
+    work at full f32 precision instead of an unsupported vector scatter);
+  * the compacted sub-tile lands at ``out[:, base : base + SUB]`` where
     ``base`` is the global running count — slots past the sub-tile's own
     count hold zeros and are overwritten by the next sub-tile's store (TPU
-    grids iterate sequentially, so later stores win).
+    grids iterate sequentially, so later stores win).  Stores start on a
+    lane tile: the scatter targets a ``[C, SUB + LANES]`` window that
+    begins at the tile holding ``base``, and the rows already written below
+    ``base`` in that tile are kept.
 
 Capacity semantics match the ``nonzero(size=cap)`` oracle: qualifying rows
 with global position >= cap are dropped, slots in [count, cap) are zero.
-The output buffer is padded by one sub-tile so an almost-full store never
-writes out of bounds (stores whose base would pass ``cap`` clamp into the
-trimmed pad region).
+The output buffer is padded by one store window so an almost-full store
+never writes out of bounds (stores whose base would pass ``cap`` clamp into
+the trimmed pad region).
 
 The returned count is exact and independent of ``cap``; it rides in an i32
 [1, LANES] tile that doubles as the running-offset carry between grid steps.
 
 Two variants share that per-sub-tile compaction core:
 
-  * the **resident** kernel above keeps the whole ``[C, cap + SUB]`` output
+  * the **resident** kernel above keeps the whole padded ``[C, cap]`` output
     in VMEM, so ``cap`` is bounded by the ~8 MB VMEM budget — fine for the
     low-selectivity points, impossible for the 6M-row sweep at high
     selectivity;
   * the **streaming** kernel (:func:`block_compact_stream`) keeps the output
-    in HBM (``pltpu.ANY``) and emits each completed SUB-wide tile with a
+    in HBM (``pl.ANY``) and emits each completed SUB-wide tile with a
     double-buffered manual DMA (:mod:`repro.kernels.pipeline`), overlapping
-    the copy of tile *i* with the mask/cumsum/scatter-matmul compute of the
+    the copy of tile *i* with the mask/prefix/scatter-matmul compute of the
     sub-tiles that fill tile *i+1*.  Capacity is HBM-bounded.
 
 The streaming write path cannot reuse the resident kernel's overlapping-
@@ -70,10 +73,43 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import pipeline
-from repro.kernels.compat import CompilerParams
 
 LANES = 128
 SUB = 512  # sub-tile width: the scatter permutation is [SUB, SUB] f32
+_WINDOW = SUB + LANES  # resident store window: one sub-tile plus the lane skew
+
+
+def _exclusive_prefix(m: jax.Array) -> jax.Array:
+    """Exclusive prefix sum along the lanes of a ``[1, SUB]`` i32 row.
+
+    Log-step shifted adds (Hillis-Steele) on lane rotations: Mosaic lowers
+    ``pltpu.roll``, not ``jnp.cumsum``.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    x = m
+    shift = 1
+    while shift < m.shape[1]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0)
+        shift *= 2
+    return x - m
+
+
+def _scatter(sub: jax.Array, m: jax.Array, fill, width: int) -> jax.Array:
+    """Pack the qualifying rows of one sub-tile into ``[C, width]`` slots.
+
+    Qualifying row ``r`` lands at slot ``fill + (exclusive prefix of m)[r]``;
+    every other slot is zero.  The scatter is a matmul against the 0/1
+    matrix ``P[j, r]`` (MXU work instead of an unsupported vector scatter),
+    at full f32 precision so every value is copied exactly.
+    """
+    target = jnp.where(m != 0, _exclusive_prefix(m) + fill, -1)  # [1, SUB]
+    slots = jax.lax.broadcasted_iota(jnp.int32, (width, SUB), 0)
+    perm = (slots == target).astype(jnp.float32)
+    return jax.lax.dot_general(
+        sub, perm, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _kernel(cols_ref, mask_ref, out_ref, cnt_ref, *, cap: int):
@@ -85,29 +121,37 @@ def _kernel(cols_ref, mask_ref, out_ref, cnt_ref, *, cap: int):
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     bn = cols_ref.shape[1]
-    slot_ids = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _WINDOW), 1)
 
     def body(s, base):
         m = mask_ref[:, pl.ds(s * SUB, SUB)]  # [1, SUB] i32
         sub = cols_ref[:, pl.ds(s * SUB, SUB)]  # [C, SUB]
-        pos = jnp.cumsum(m, axis=1) - m  # exclusive prefix: target slot
-        cnt = jnp.sum(m)
-        # P[r, j] = qualifying row r goes to slot j; scatter via MXU.
-        perm = (
-            (pos.reshape(SUB, 1) == slot_ids) & (m.reshape(SUB, 1) != 0)
-        ).astype(jnp.float32)
-        packed = jax.lax.dot_general(
-            sub, perm, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
         # Rows past cap are dropped: clamp the store into the pad region,
         # where it only ever overwrites other dropped rows.
         start = jnp.minimum(base, cap)
-        out_ref[:, pl.ds(start, SUB)] = packed
-        return base + cnt
+        # Stores must start on a lane tile: scatter into a window that
+        # begins at the tile holding ``start`` and keep the rows already
+        # written below ``start`` in that tile.
+        skew = jax.lax.rem(start, LANES)
+        at = pl.ds(pl.multiple_of(start - skew, LANES), _WINDOW)
+        window = _scatter(sub, m, skew, _WINDOW)
+        out_ref[:, at] = jnp.where(lane < skew, out_ref[:, at], window)
+        return base + jnp.sum(m)
 
     base0 = cnt_ref[0, 0]
     total = jax.lax.fori_loop(0, bn // SUB, body, base0)
     cnt_ref[...] = jnp.full((1, LANES), total, jnp.int32)
+
+
+def _resident_width(cap: int) -> int:
+    """Columns of the resident output: the last store window starts on the
+    lane tile holding ``cap`` and must stay in bounds."""
+    return cap - cap % LANES + _WINDOW
+
+
+def resident_bytes(c: int, cap: int) -> int:
+    """VMEM the resident kernel's ``[c, cap]`` output occupies, padding included."""
+    return c * _resident_width(cap) * 4
 
 
 def block_compact(
@@ -129,6 +173,7 @@ def block_compact(
     assert bn % SUB == 0, (bn, SUB)
     assert cap >= 1
 
+    width = _resident_width(cap)
     out, cnt = pl.pallas_call(
         functools.partial(_kernel, cap=cap),
         grid=(n // bn,),
@@ -137,14 +182,14 @@ def block_compact(
             pl.BlockSpec((1, bn), lambda i: (0, i)),
         ],
         out_specs=(
-            pl.BlockSpec((c, cap + SUB), lambda i: (0, 0)),
+            pl.BlockSpec((c, width), lambda i: (0, 0)),
             pl.BlockSpec((1, LANES), lambda i: (0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((c, cap + SUB), jnp.float32),
+            jax.ShapeDtypeStruct((c, width), jnp.float32),
             jax.ShapeDtypeStruct((1, LANES), jnp.int32),
         ),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -184,7 +229,6 @@ def _stream_kernel(
 
     bn = cols_ref.shape[1]
     pad_tile = cap_ceil // SUB  # first tile index wholly past cap: not emitted
-    slot_ids = jax.lax.broadcasted_iota(jnp.int32, (SUB, 2 * SUB), 1)
 
     def body(s, st):
         total, fill, tile, seq, carry = st
@@ -193,14 +237,8 @@ def _stream_kernel(
         # Slot among (carry rows + this sub-tile's qualifiers): the widened
         # scatter lands row r at fill + (exclusive prefix of mask)[r], so
         # the carry merge is a plain add against disjoint zero slots.
-        pos = jnp.cumsum(m, axis=1) - m + fill
         cnt = jnp.sum(m)
-        perm = (
-            (pos.reshape(SUB, 1) == slot_ids) & (m.reshape(SUB, 1) != 0)
-        ).astype(jnp.float32)
-        window = jax.lax.dot_general(
-            sub, perm, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [C, 2*SUB]: qualifying rows at slots [fill, fill + cnt)
+        window = _scatter(sub, m, fill, 2 * SUB)  # qualifiers at [fill, fill + cnt)
         merged = carry + window[:, :SUB]
         spill = window[:, SUB:]
         new_fill = fill + cnt
@@ -284,10 +322,10 @@ def stream_chunk(
             pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((1, LANES), lambda i: (0, 0)),
             pl.BlockSpec((c, SUB), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, LANES), lambda i: (0, 0)),
             pl.BlockSpec((c, SUB), lambda i: (0, 0)),
         ),
@@ -298,7 +336,7 @@ def stream_chunk(
         ),
         scratch_shapes=list(pipeline.emit_slots(c, SUB, jnp.float32)),
         input_output_aliases={4: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

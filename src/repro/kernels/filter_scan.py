@@ -15,8 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 - dtype/memory-space helpers
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
@@ -37,8 +36,8 @@ def _kernel(bounds_ref, cols_ref, out_ref):
     prod = jnp.where(mask, c2.astype(jnp.float32) * c3.astype(jnp.float32), 0.0)
     cnt = mask.astype(jnp.float32)
     # lane 0 accumulates sum, lane 1 count; remaining lanes stay zero
-    upd = jnp.zeros((1, LANES), jnp.float32)
-    upd = upd.at[0, 0].set(jnp.sum(prod)).at[0, 1].set(jnp.sum(cnt))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    upd = jnp.where(lane == 0, jnp.sum(prod), jnp.where(lane == 1, jnp.sum(cnt), 0.0))
     out_ref[...] += upd
 
 
@@ -67,7 +66,7 @@ def filter_agg(
         ],
         out_specs=pl.BlockSpec((1, LANES), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -20,8 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 - dtype/memory-space helpers
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # m/l scratch replicated across the lane dim
@@ -112,7 +111,7 @@ def flash_attention(
             pltpu.VMEM((bq, LANES), jnp.float32),  # l
             pltpu.VMEM((bq, dh), jnp.float32),  # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

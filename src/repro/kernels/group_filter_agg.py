@@ -20,18 +20,21 @@ This kernel makes any such query ONE pass over a ``[C, N]`` column block:
     ``charge = disc_price * (1 + tax)`` are term products evaluated
     in-register, never materialized in HBM;
   * per-group accumulation for G dictionary-coded groups lands in a
-    revisited ``[G, LANES]`` VMEM tile via a one-hot MXU matmul
+    ``[G, LANES]`` slot of a VMEM-resident output via a one-hot MXU matmul
     (``onehot[G, bn] @ vals[bn, A+1]``); TPU grids iterate sequentially, so
-    the running accumulator across blocks is safe (same trick as
-    ``filter_scan``).
+    the running accumulator across blocks is safe;
+  * the constants of both programs carry a leading program dimension B, so
+    one pass over the columns serves B requests of the same query shape
+    (scan sharing); a single query is ``B = 1``.
 
 Padding contract: rows whose key is outside ``[0, num_groups)`` (the ops
 wrapper pads with -1) match no one-hot row and therefore contribute to no
 group, regardless of what the predicate program evaluates to on padded
 junk — padding correctness does not depend on the program.
 
-Output layout: ``out[g, a]`` = sum of aggregate ``a`` over masked rows of
-group ``g`` for ``a < A``; ``out[g, A]`` = masked row count of group ``g``.
+Output layout: ``out[b, g, a]`` = sum of aggregate ``a`` over the rows of
+group ``g`` that program ``b`` selects, for ``a < A``; ``out[b, g, A]`` =
+that masked row count.
 """
 from __future__ import annotations
 
@@ -42,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels.compat import CompilerParams
 
 LANES = 128
 
@@ -134,40 +136,36 @@ def encode_aggregates(aggs) -> tuple[jax.Array, jax.Array]:
 
 
 # ---------------------------------------------------------------------------
-def _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds: int, prog=None):
-    """Row mask [1, bn] from the SMEM predicate program (all preds ANDed).
+def _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds: int, prog):
+    """Row mask [1, bn] as f32 0/1 from program ``prog``'s predicates (ANDed).
 
-    ``prog`` indexes the program slot of a batched ``[B, K, 2]`` constants
-    table (the multi-program dispatch path); ``None`` reads the flat
-    ``[K, 2]`` layout.
+    Each predicate's two candidate outcomes are converted to f32 before the
+    opcode select: Mosaic cannot select between boolean vectors.
     """
     bn = cols_ref.shape[1]
-    mask = jnp.ones((1, bn), jnp.bool_)
+    mask = jnp.ones((1, bn), jnp.float32)
     for k in range(num_preds):
         kind = pred_ops_ref[k, 0]
         a = pred_ops_ref[k, 1]
         b = pred_ops_ref[k, 2]
-        if prog is None:
-            lo = pred_consts_ref[k, 0]
-            hi = pred_consts_ref[k, 1]
-        else:
-            lo = pred_consts_ref[prog, k, 0]
-            hi = pred_consts_ref[prog, k, 1]
+        lo = pred_consts_ref[prog, k, 0]
+        hi = pred_consts_ref[prog, k, 1]
         ca = cols_ref[pl.ds(a, 1), :]
         cb = cols_ref[pl.ds(b, 1), :]
-        in_range = (ca >= lo) & (ca < hi)
-        mask &= jnp.where(kind == PRED_RANGE, in_range, ca < cb)
+        in_range = ((ca >= lo) & (ca < hi)).astype(jnp.float32)
+        less = (ca < cb).astype(jnp.float32)
+        mask = mask * jnp.where(kind == PRED_RANGE, in_range, less)
     return mask
 
 
-def _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a: int, prog=None):
+def _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a: int, prog):
     """Per-row value [1, bn] of aggregate ``a``: the product of its terms."""
     bn = cols_ref.shape[1]
     val = jnp.ones((1, bn), jnp.float32)
     for t in range(MAX_TERMS):
         mode = agg_ops_ref[a, 2 * t]
         col = agg_ops_ref[a, 2 * t + 1]
-        const = agg_consts_ref[a, t] if prog is None else agg_consts_ref[prog, a, t]
+        const = agg_consts_ref[prog, a, t]
         c = cols_ref[pl.ds(col, 1), :].astype(jnp.float32)
         term = jnp.where(mode == TERM_COL, c, 1.0)
         term = jnp.where(mode == TERM_ONE_MINUS, 1.0 - c, term)
@@ -180,25 +178,26 @@ def _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a: int, prog=None):
 
 def _kernel(
     pred_ops_ref,
-    pred_consts_ref,
+    pred_consts_ref,  # [B, K, 2] SMEM: per-program predicate constants
     agg_ops_ref,
-    agg_consts_ref,
+    agg_consts_ref,  # [B, A, MAX_TERMS] SMEM: per-program term constants
     cols_ref,
     keys_ref,
-    out_ref,
+    out_ref,  # the whole [B, G, LANES] output, resident for the entire grid
     *,
     num_groups: int,
     num_preds: int,
     num_aggs: int,
 ):
-    i = pl.program_id(0)
+    i = pl.program_id(0)  # data block (outer grid dim)
+    prog = pl.program_id(1)  # program slot (inner grid dim)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[prog] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
     bn = cols_ref.shape[1]
-    maskf = _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds).astype(jnp.float32)
+    maskf = _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds, prog)
 
     # Masked one-hot group membership [G, bn]; padded rows carry key -1 and
     # match no row of the iota, so they vanish from every group.
@@ -209,109 +208,25 @@ def _kernel(
     # Per-row aggregate values [A + 1, bn]; the trailing row of ones becomes
     # the per-group masked count through the same matmul.
     rows = [
-        _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a) for a in range(num_aggs)
+        _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a, prog)
+        for a in range(num_aggs)
     ]
     rows.append(jnp.ones((1, bn), jnp.float32))
     vals = jnp.concatenate(rows, axis=0)
 
     # [G, bn] x [A+1, bn]^T -> [G, A+1]: the whole grouped aggregation for
-    # this block in one MXU pass, accumulated into the revisited output tile.
+    # this block in one MXU pass at full f32 precision, accumulated into
+    # this program's slot of the resident output.
     upd = jax.lax.dot_general(
-        onehot, vals, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, vals, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    out_ref[...] += jnp.pad(upd, ((0, 0), (0, LANES - (num_aggs + 1))))
+    out_ref[prog] += jnp.pad(upd, ((0, 0), (0, LANES - (num_aggs + 1))))
 
 
 def group_filter_agg(
-    cols: jax.Array,  # [C, N] f32 column block
-    keys: jax.Array,  # [1, N] i32 dictionary-coded group ids (may be -1 = pad)
-    pred_ops: jax.Array,  # [K, 3] i32 predicate program
-    pred_consts: jax.Array,  # [K, 2] f32
-    agg_ops: jax.Array,  # [A, 2*MAX_TERMS] i32 aggregate program
-    agg_consts: jax.Array,  # [A, MAX_TERMS] f32
-    *,
-    num_groups: int,
-    block_n: int = 16384,
-    interpret: bool = False,
-) -> jax.Array:
-    """Returns [num_groups, A + 1] f32: per-group aggregate sums + count."""
-    _, n = cols.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
-    num_preds = pred_ops.shape[0]
-    num_aggs = agg_ops.shape[0]
-    assert num_aggs + 1 <= LANES, num_aggs
-    assert num_groups >= 1
-
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel,
-            num_groups=num_groups,
-            num_preds=num_preds,
-            num_aggs=num_aggs,
-        ),
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((cols.shape[0], bn), lambda i: (0, i)),
-            pl.BlockSpec((1, bn), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((num_groups, LANES), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_groups, LANES), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(pred_ops, pred_consts, agg_ops, agg_consts, cols, keys)
-    return out[:, : num_aggs + 1]
-
-
-# ---------------------------------------------------------------------------
-# Multi-program dispatch: B constant sets, one HBM pass (scan sharing).
-def _kernel_multi(
-    pred_ops_ref,
-    pred_consts_ref,  # [B, K, 2] SMEM — per-program predicate constants
-    agg_ops_ref,
-    agg_consts_ref,  # [B, A, MAX_TERMS] SMEM — per-program term constants
-    cols_ref,
-    keys_ref,
-    out_ref,  # [1, G, LANES] block of the [B, G, LANES] output
-    *,
-    num_groups: int,
-    num_preds: int,
-    num_aggs: int,
-):
-    i = pl.program_id(0)  # data block (outer grid dim)
-    b = pl.program_id(1)  # program slot (inner grid dim)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    bn = cols_ref.shape[1]
-    maskf = _eval_mask(
-        pred_ops_ref, pred_consts_ref, cols_ref, num_preds, prog=b
-    ).astype(jnp.float32)
-    keys = keys_ref[...]
-    group_ids = jax.lax.broadcasted_iota(jnp.int32, (num_groups, bn), 0)
-    onehot = (group_ids == keys).astype(jnp.float32) * maskf
-    rows = [
-        _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a, prog=b)
-        for a in range(num_aggs)
-    ]
-    rows.append(jnp.ones((1, bn), jnp.float32))
-    vals = jnp.concatenate(rows, axis=0)
-    upd = jax.lax.dot_general(
-        onehot, vals, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    out_ref[...] += jnp.pad(upd, ((0, 0), (0, LANES - (num_aggs + 1))))[None]
-
-
-def group_filter_agg_multi(
-    cols: jax.Array,  # [C, N] f32 column block — scanned ONCE for all programs
+    cols: jax.Array,  # [C, N] f32 column block, scanned once for all programs
     keys: jax.Array,  # [1, N] i32 dictionary-coded group ids (may be -1 = pad)
     pred_ops: jax.Array,  # [K, 3] i32 predicate program, shared across the batch
     pred_consts: jax.Array,  # [B, K, 2] f32 per-program predicate constants
@@ -322,19 +237,21 @@ def group_filter_agg_multi(
     block_n: int = 16384,
     interpret: bool = False,
 ) -> jax.Array:
-    """Scan-shared batch of ``group_filter_agg``: B programs, one HBM pass.
+    """B programs of one query shape over one HBM pass of the columns.
 
-    All programs share one opcode structure (same query shape) but carry
-    their own constants — N concurrent q6 requests with different predicate
-    bounds become one kernel invocation.  The grid is ``(blocks, B)`` with
-    the program slot innermost: each ``[C, bn]`` column block's index map is
-    constant across the inner dimension, so Pallas keeps the block resident
-    in VMEM while every program runs over it, and HBM sees each row exactly
-    once regardless of B.  Per program the block-accumulation order is
-    identical to the single-program kernel, so ``out[b]`` is bit-equal to
-    ``group_filter_agg(..., pred_consts[b], ..., agg_consts[b], ...)``.
+    All programs share one opcode structure but carry their own constants,
+    so N concurrent q6 requests with different predicate bounds become one
+    kernel invocation (scan sharing); a single query is the ``B = 1`` case.
+    The grid is ``(blocks, B)`` with the program slot innermost: the
+    ``[C, bn]`` column block's index map is constant across the inner
+    dimension, so the block stays in VMEM while every program runs over it.
+    The ``[B, G, LANES]`` output has a constant index map, so it stays
+    resident in VMEM for the whole grid and is written back once at the
+    end; each program accumulates into its own slot, visiting the data
+    blocks in order, as the ``B = 1`` call does.
 
-    Returns ``[B, num_groups, A + 1]`` f32.
+    Returns ``[B, num_groups, A + 1]`` f32: per-group aggregate sums, then
+    the masked count.
     """
     _, n = cols.shape
     bn = min(block_n, n)
@@ -348,7 +265,7 @@ def group_filter_agg_multi(
 
     out = pl.pallas_call(
         functools.partial(
-            _kernel_multi,
+            _kernel,
             num_groups=num_groups,
             num_preds=num_preds,
             num_aggs=num_aggs,
@@ -362,9 +279,9 @@ def group_filter_agg_multi(
             pl.BlockSpec((cols.shape[0], bn), lambda i, b: (0, i)),
             pl.BlockSpec((1, bn), lambda i, b: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, num_groups, LANES), lambda i, b: (b, 0, 0)),
+        out_specs=pl.BlockSpec((num_progs, num_groups, LANES), lambda i, b: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_progs, num_groups, LANES), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 - dtype/memory-space helpers
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, nd):
@@ -66,7 +65,7 @@ def gmm(
         out_specs=pl.BlockSpec((1, bc, bf), lambda e_, ci, fi, di: (e_, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), lhs.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,10 +1,11 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-Each op auto-selects interpret mode off-TPU (the kernels VALIDATE on CPU via
-the interpreter and TARGET TPU), pads awkward shapes up to tile multiples,
-and exposes a `use_pallas=False` escape hatch that routes to the ref oracle
-— the models use that flag so CPU smoke tests and TPU runs share one code
-path.
+The kernels target the TPU.  On a TPU backend they always compile for the
+chip; on the CPU backend (the test suite) they run in Pallas interpret mode;
+any other backend is an error.  Each op pads awkward shapes up to tile
+multiples (or refuses shapes its kernel cannot take) and exposes a
+``use_pallas=False`` escape hatch that routes to the ref oracle, so callers
+choose the oracle explicitly, never by accident of shape.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from repro.kernels import ref
 from repro.kernels.block_compact import SUB as _COMPACT_SUB
 from repro.kernels.block_compact import block_compact as _compact_kernel
 from repro.kernels.block_compact import (
+    resident_bytes,
     stream_chunk as _stream_chunk,
     stream_finalize as _stream_finalize,
     stream_init as _stream_init,
@@ -25,17 +27,21 @@ from repro.kernels.decode_attention import decode_attention as _decode_kernel
 from repro.kernels.filter_scan import filter_agg as _filter_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.group_filter_agg import group_filter_agg as _group_kernel
-from repro.kernels.group_filter_agg import group_filter_agg_multi as _group_multi_kernel
 from repro.kernels.moe_gmm import gmm as _gmm_kernel
 from repro.kernels.ssd_scan import ssd_intra as _ssd_kernel
 
 
-def on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
-def _interpret() -> bool:
-    return not on_tpu()
+def interpret_mode() -> bool:
+    """Whether the kernels run in Pallas interpret mode: never on a TPU,
+    always on the CPU; other backends are refused."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run on a TPU or, interpreted, on the CPU; backend is {backend!r}"
+    )
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
@@ -59,10 +65,13 @@ def flash_attention(
         return ref.flash_attention_ref(q, k, v, causal=causal)
     bq = min(block_q, q.shape[1])
     bk = min(block_k, k.shape[1])
-    if q.shape[1] % bq or k.shape[1] % bk:  # ragged tails -> oracle
-        return ref.flash_attention_ref(q, k, v, causal=causal)
+    if q.shape[1] % bq or k.shape[1] % bk:
+        raise ValueError(
+            f"flash_attention: sequence lengths {q.shape[1]}/{k.shape[1]} are not "
+            f"multiples of the blocks {bq}/{bk}; pass use_pallas=False for the oracle"
+        )
     return _flash_kernel(
-        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=_interpret()
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=interpret_mode()
     )
 
 
@@ -74,7 +83,7 @@ def decode_attention(q, k, v, kv_len, *, block_k: int = 512, use_pallas: bool = 
     k_p, s0 = _pad_to(k, 1, min(block_k, k.shape[1]))
     v_p, _ = _pad_to(v, 1, min(block_k, v.shape[1]))
     return _decode_kernel(
-        q, k_p, v_p, kv_len.astype(jnp.int32), block_k=block_k, interpret=_interpret()
+        q, k_p, v_p, kv_len.astype(jnp.int32), block_k=block_k, interpret=interpret_mode()
     )
 
 
@@ -92,7 +101,7 @@ def ssd_intra(x, bmat, cmat, dt, a, *, chunk: int = 128, use_pallas: bool = True
             ys.append(y)
             sts.append(st)
         return jnp.concatenate(ys, 1), jnp.stack(sts, 1)
-    return _ssd_kernel(x, bmat, cmat, dt, a, chunk=chunk, interpret=_interpret())
+    return _ssd_kernel(x, bmat, cmat, dt, a, chunk=chunk, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d", "use_pallas"))
@@ -104,9 +113,13 @@ def gmm(lhs, rhs, *, block_c: int = 256, block_f: int = 256, block_d: int = 512,
     e, c, d = lhs.shape
     f = rhs.shape[-1]
     bc, bf, bd = min(block_c, c), min(block_f, f), min(block_d, d)
-    if c % bc or f % bf or d % bd:
-        return ref.gmm_ref(lhs, rhs)
-    return _gmm_kernel(lhs, rhs, block_c=bc, block_f=bf, block_d=bd, interpret=_interpret())
+    # Zero padding is exact: padded d contributes nothing, padded c/f are cut.
+    lhs, _ = _pad_to(lhs, 1, bc)
+    lhs, _ = _pad_to(lhs, 2, bd)
+    rhs, _ = _pad_to(rhs, 1, bd)
+    rhs, _ = _pad_to(rhs, 2, bf)
+    out = _gmm_kernel(lhs, rhs, block_c=bc, block_f=bf, block_d=bd, interpret=interpret_mode())
+    return out[:, :c, :f]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "use_pallas"))
@@ -120,7 +133,21 @@ def filter_agg(cols, lo, hi, lo2, hi2, *, block_n: int = 16384, use_pallas: bool
         pad = cols_p.shape[1] - cols.shape[1]
         filler = jnp.full((cols.shape[0], pad), jnp.finfo(jnp.float32).max, cols.dtype)
         cols_p = jnp.concatenate([cols, filler], axis=1)
-    return _filter_kernel(cols_p, lo, hi, lo2, hi2, block_n=block_n, interpret=_interpret())
+    return _filter_kernel(cols_p, lo, hi, lo2, hi2, block_n=block_n, interpret=interpret_mode())
+
+
+def _pad_group_rows(cols, keys, block_n: int):
+    """Pad the row axis to a whole number of blocks.  Padded rows carry key
+    -1: they match no group whatever the predicate program evaluates to on
+    the zero-filled columns."""
+    keys = keys.reshape(1, -1).astype(jnp.int32)
+    n = cols.shape[1]
+    bn = min(block_n, n)
+    target = -(-n // bn) * bn
+    if target != n:
+        cols = jnp.pad(cols, ((0, 0), (0, target - n)))
+        keys = jnp.pad(keys, ((0, 0), (0, target - n)), constant_values=-1)
+    return cols, keys, bn
 
 
 @functools.partial(
@@ -136,24 +163,17 @@ def group_filter_agg(
     predicate and aggregate programs (see kernels/group_filter_agg.py —
     ``encode_predicates`` / ``encode_aggregates`` build them).  Returns
     [num_groups, A + 1]: per-group aggregate sums, then the masked count.
+    This is the one-program batch of :func:`group_filter_agg_multi`.
     """
     if not use_pallas:
         return ref.group_filter_agg_ref(
             cols, keys, pred_ops, pred_consts, agg_ops, agg_consts, num_groups
         )
-    keys = keys.reshape(1, -1).astype(jnp.int32)
-    n = cols.shape[1]
-    bn = min(block_n, n)
-    target = -(-n // bn) * bn
-    if target != n:
-        # Padded rows carry key -1: they match no group regardless of what
-        # the predicate program evaluates to on the zero-filled columns.
-        cols = jnp.pad(cols, ((0, 0), (0, target - n)))
-        keys = jnp.pad(keys, ((0, 0), (0, target - n)), constant_values=-1)
+    cols, keys, bn = _pad_group_rows(cols, keys, block_n)
     return _group_kernel(
-        cols, keys, pred_ops, pred_consts, agg_ops, agg_consts,
-        num_groups=num_groups, block_n=bn, interpret=_interpret(),
-    )
+        cols, keys, pred_ops, pred_consts[None], agg_ops, agg_consts[None],
+        num_groups=num_groups, block_n=bn, interpret=interpret_mode(),
+    )[0]
 
 
 @functools.partial(
@@ -168,30 +188,25 @@ def group_filter_agg_multi(
     ``pred_consts``/``agg_consts`` carry a leading program dimension
     (``[B, K, 2]`` / ``[B, A, MAX_TERMS]``) and are *traced inputs*, not
     trace-time constants — one compiled executable serves any predicate
-    bounds of the same query shape.  Returns ``[B, num_groups, A + 1]``;
-    slot ``b`` is bit-equal to the single-program call with that program's
-    constants (same block-accumulation order).
+    bounds of the same query shape.  Returns ``[B, num_groups, A + 1]``.
+    The single-program call runs the same kernel with B = 1, and each slot
+    visits the data blocks in the same order, so slot ``b`` matches the
+    single-program call with that program's constants: counts exactly,
+    float sums to within the order of the additions inside one block's dot,
+    which the compiler may choose differently for different B.
     """
     if not use_pallas:
         return ref.group_filter_agg_multi_ref(
             cols, keys, pred_ops, pred_consts, agg_ops, agg_consts, num_groups
         )
-    keys = keys.reshape(1, -1).astype(jnp.int32)
-    n = cols.shape[1]
-    bn = min(block_n, n)
-    target = -(-n // bn) * bn
-    if target != n:
-        # Same padding contract as the single-program wrapper: key -1
-        # matches no group, so padded rows vanish from every program.
-        cols = jnp.pad(cols, ((0, 0), (0, target - n)))
-        keys = jnp.pad(keys, ((0, 0), (0, target - n)), constant_values=-1)
-    return _group_multi_kernel(
+    cols, keys, bn = _pad_group_rows(cols, keys, block_n)
+    return _group_kernel(
         cols, keys, pred_ops, pred_consts, agg_ops, agg_consts,
-        num_groups=num_groups, block_n=bn, interpret=_interpret(),
+        num_groups=num_groups, block_n=bn, interpret=interpret_mode(),
     )
 
 
-#: VMEM the resident block_compact may spend on its [C, cap + SUB] output
+#: VMEM the resident block_compact may spend on its padded [C, cap] output
 #: before ``stream="auto"`` switches to the HBM-streaming variant.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
@@ -232,10 +247,9 @@ def block_compact(
         cols = jnp.pad(cols, ((0, 0), (0, target - n)))
         mask = jnp.pad(mask, ((0, 0), (0, target - n)))
     if stream == "auto":
-        resident_bytes = c * (cap + _COMPACT_SUB) * 4
-        stream = "always" if resident_bytes > VMEM_BUDGET_BYTES else "never"
+        stream = "always" if resident_bytes(c, cap) > VMEM_BUDGET_BYTES else "never"
     if stream == "never":
-        return _compact_kernel(cols, mask, cap, block_n=bn, interpret=_interpret())
+        return _compact_kernel(cols, mask, cap, block_n=bn, interpret=interpret_mode())
     if stream != "always":
         raise ValueError(f"stream must be auto/always/never, got {stream!r}")
     # Chunked driver: one streaming-kernel invocation per chunk_n rows, the
@@ -247,6 +261,6 @@ def block_compact(
         e = min(s + cn, target)
         state = _stream_chunk(
             state, cols[:, s:e], mask[:, s:e], cap,
-            block_n=bn, interpret=_interpret(),
+            block_n=bn, interpret=interpret_mode(),
         )
     return _stream_finalize(state, cap)

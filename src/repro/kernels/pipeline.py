@@ -1,7 +1,7 @@
 """Double-buffered VMEM->HBM DMA emit pipeline (manual async copies).
 
 Pallas pipelines *inputs* for free (BlockSpec index maps), but kernels whose
-output lives in HBM (``pltpu.ANY`` memory space) must move every result tile
+output lives in HBM (``pl.ANY`` memory space) must move every result tile
 themselves.  The naive way — compute a tile, DMA it, wait, compute the next —
 serializes the store path behind compute.  This module packages the standard
 double-buffering discipline so every out-of-VMEM kernel in the repo shares
@@ -9,9 +9,10 @@ one implementation (``block_compact``'s streaming variant is the first user;
 the planned HBM-streaming ``group_filter_agg`` is written against the same
 surface):
 
-  * a staging scratch of :data:`NBUF` tile slots lives in VMEM, flat-packed
-    as ``[NBUF * rows, width]`` (dynamic indexing on the second-minor axis
-    lowers on TPU; a leading buffer axis may not);
+  * a staging scratch of :data:`NBUF` tile slots lives in VMEM as
+    ``[NBUF, rows, width]``; a slot is a whole tile, so indexing the
+    leading axis keeps every slot aligned to the TPU's sublane tiling
+    whatever ``rows`` is;
   * :func:`emit_tile` stages tile ``seq`` into slot ``seq % NBUF`` and
     starts its async copy — the DMA of tile ``seq`` is in flight while the
     caller computes tile ``seq + 1``, which is the whole point;
@@ -56,33 +57,18 @@ from jax.experimental.pallas import tpu as pltpu
 #: cheaper than the copy, which none of our emitters are.
 NBUF = 2
 
-#: f32 sublane granule: slot strides are padded to it so the dynamic
-#: second-minor offsets (``slot * stride``) stay aligned on TPU.
-_SUBLANE = 8
-
-
-def _stride(rows: int) -> int:
-    return -(-rows // _SUBLANE) * _SUBLANE
-
-
 def emit_slots(rows: int, width: int, dtype) -> tuple:
     """The two ``scratch_shapes`` entries an emit pipeline needs.
 
     Returns ``(vmem_stage, dma_semaphores)`` for a ``[rows, width]`` tile
-    shape: a flat ``[NBUF * stride, width]`` staging buffer (``stride`` =
-    ``rows`` padded to the sublane granule) plus one DMA semaphore per
-    slot.  Splat into ``pallas_call(scratch_shapes=[...])`` and pass the
+    shape: an ``[NBUF, rows, width]`` staging buffer plus one DMA semaphore
+    per slot.  Splat into ``pallas_call(scratch_shapes=[...])`` and pass the
     resulting two refs to :func:`emit_tile` / :func:`drain`.
     """
     return (
-        pltpu.VMEM((NBUF * _stride(rows), width), dtype),
+        pltpu.VMEM((NBUF, rows, width), dtype),
         pltpu.SemaphoreType.DMA((NBUF,)),
     )
-
-
-def _slot_rows(stage_ref, slot, rows: int):
-    stride = stage_ref.shape[0] // NBUF
-    return stage_ref.at[pl.ds(slot * stride, rows), :]
 
 
 def emit_tile(stage_ref, sem_ref, seq, tile, dst) -> None:
@@ -94,15 +80,14 @@ def emit_tile(stage_ref, sem_ref, seq, tile, dst) -> None:
     is waited first.  Side-effecting only: safe under ``pl.when``; the
     caller advances ``seq`` itself.
     """
-    rows = tile.shape[0]
     slot = jax.lax.rem(seq, NBUF)
-    src = _slot_rows(stage_ref, slot, rows)
+    src = stage_ref.at[slot]
 
     @pl.when(seq >= NBUF)
     def _settle_previous():
         pltpu.make_async_copy(src, dst, sem_ref.at[slot]).wait()
 
-    stage_ref[pl.ds(slot * (stage_ref.shape[0] // NBUF), rows), :] = tile
+    stage_ref[slot] = tile
     pltpu.make_async_copy(src, dst, sem_ref.at[slot]).start()
 
 
@@ -113,12 +98,11 @@ def drain(stage_ref, sem_ref, seq, dst_like) -> None:
     wait only uses its size — see the module docstring).  Must run before
     the kernel or grid step finishes so no scratch semaphore is left armed.
     """
-    rows = dst_like.shape[0]
     for k in range(NBUF):
 
         @pl.when(seq > k)
         def _settle(k=k):
             slot = jax.lax.rem(seq - 1 - k, NBUF)
             pltpu.make_async_copy(
-                _slot_rows(stage_ref, slot, rows), dst_like, sem_ref.at[slot]
+                stage_ref.at[slot], dst_like, sem_ref.at[slot]
             ).wait()

@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, b_ref, c_ref, dt_ref, dta_ref, y_ref, st_ref, *, q):
@@ -93,7 +93,7 @@ def ssd_intra(
             jax.ShapeDtypeStruct((b, s, h, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, h, p, n), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

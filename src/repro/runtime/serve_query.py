@@ -9,7 +9,8 @@ completions — but the batching axis differs: where decode slots batch
 of one query shape.  N pending requests with different predicate constants
 coalesce into one SMEM-program batch (`kernels.ops.group_filter_agg_multi`)
 over a single pass through the column data; per-request results come back
-de-multiplexed, bit-equal to serial execution (tests/test_serving.py).
+de-multiplexed and equal serial execution: counts exactly, float sums
+within a few ulps (tests/test_serving.py).
 
 Latency is measured from each request's *scheduled* open-loop arrival time
 — queueing delay included — so an overloaded server shows up as tail
@@ -239,6 +240,7 @@ def main(argv: list[str] | None = None) -> int:
     """
     from repro.core import config as config_mod
     from repro.core.box import Box
+    from repro.core.device import enable_compile_cache
 
     p = argparse.ArgumentParser(
         prog="repro.runtime.serve_query",
@@ -249,6 +251,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--format", choices=("csv", "md", "json"), default="csv")
     p.add_argument("--out", default=None, help="write report here instead of stdout")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     serve_cfg = config_mod.ServeConfig.from_args(args)
     sweep_cfg = config_mod.SweepConfig.from_args(args)

@@ -8,11 +8,10 @@ TCP-vs-RDMA contrast:
   shardmap — explicit jax.lax.p* inside shard_map (the "kernel-bypass"
              path: the schedule is exactly what you wrote).
 
-On this CPU container jax.devices() is 1, so collectives degenerate to
-copies — wall-times are only meaningful relatively; the REAL evaluation of
-this task is the dry-run roofline's collective term (launch/roofline.py).
-benchmarks/bench_network.py re-execs itself with forced host devices to get
-a real multi-device mesh.
+The mesh spans every device of the process.  On one device the
+collectives degenerate to copies; ``python chip_smoke.py --four-chips``
+runs all_reduce and all_gather under both schedules on a four-chip mesh and
+checks the results against numpy.
 """
 from __future__ import annotations
 
@@ -30,11 +29,68 @@ from repro.core.timing import measure
 _SIZES = {"32KB": 1 << 13, "1MB": 1 << 18, "32MB": 1 << 23, "256MB": 1 << 26}  # f32 counts
 
 
-def _mesh_1d() -> Mesh:
+def mesh_1d() -> Mesh:
+    """A 1-D mesh ``x`` over every device of the process."""
     import numpy as np
 
     devs = jax.devices()
     return Mesh(np.array(devs).reshape(len(devs)), ("x",))
+
+
+def collective(mesh: Mesh, kind: str, schedule: str, n: int):
+    """The jitted collective and its sharded input, ``arange`` over about
+    ``n`` f32 elements (rounded to whole shards) on the 1-D mesh ``x``.
+
+    ``xla`` outputs: all_reduce -> every element is the total sum;
+    reduce_scatter -> the same, sharded; all_gather -> ``x + 1``
+    replicated; all_to_all/ppermute -> the ``[n_dev, n / n_dev]``
+    transpose.  ``shardmap`` outputs are per-device results concatenated
+    along ``x``: all_reduce -> the sum of the shards on every device,
+    all_gather -> the whole input on every device.
+    """
+    n_dev = mesh.size
+    n = max(n, n_dev)  # at least one element per shard
+    n -= n % n_dev
+    x = jnp.arange(n, dtype=jnp.float32)
+    sharded = jax.device_put(x, NamedSharding(mesh, P("x")))
+
+    if schedule == "xla":
+        if kind in ("all_reduce", "reduce_scatter"):
+            fn = jax.jit(lambda v: jnp.sum(v) * jnp.ones_like(v),
+                         in_shardings=NamedSharding(mesh, P("x")),
+                         out_shardings=NamedSharding(mesh, P("x") if kind == "reduce_scatter" else P()))
+        elif kind == "all_gather":
+            fn = jax.jit(lambda v: v + 1.0,
+                         in_shardings=NamedSharding(mesh, P("x")),
+                         out_shardings=NamedSharding(mesh, P()))
+        else:  # all_to_all / ppermute approximated by a resharding transpose
+            m2 = x.reshape(n_dev, n // n_dev)
+            sharded = jax.device_put(m2, NamedSharding(mesh, P("x", None)))
+            fn = jax.jit(lambda v: v.T,
+                         in_shardings=NamedSharding(mesh, P("x", None)),
+                         out_shardings=NamedSharding(mesh, P(None, "x")))
+        return fn, sharded
+
+    # shardmap: explicit collectives; outputs flattened, out_specs P("x")
+    def body(v):
+        if kind == "all_reduce":
+            return jax.lax.psum(v, "x")
+        if kind == "all_gather":
+            return jax.lax.all_gather(v, "x", tiled=True).reshape(-1)
+        if kind == "reduce_scatter":
+            return jax.lax.psum_scatter(v, "x", tiled=True)
+        if kind == "all_to_all":
+            vv = v.reshape(n_dev, -1)
+            out = jax.lax.all_to_all(vv, "x", split_axis=0, concat_axis=0, tiled=False)
+            return out.reshape(-1)
+        # ppermute: ring shift
+        perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
+        return jax.lax.ppermute(v, "x", perm)
+
+    fn = jax.jit(
+        jax.shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
+    )
+    return fn, sharded
 
 
 @register
@@ -48,56 +104,16 @@ class NetworkTask(Task):
     default_metrics = ("bandwidth_gb_s", "avg_latency_us", "p99_latency_us")
 
     def prepare(self, ctx: TaskContext) -> None:
-        ctx.scratch["mesh"] = _mesh_1d()
+        ctx.scratch["mesh"] = mesh_1d()
 
     def run(self, ctx: TaskContext, params: dict[str, Any]) -> Samples:
         mesh = ctx.scratch["mesh"]
         n_dev = mesh.size
-        n = _SIZES[params.get("payload", "1MB")]
-        n = max(n, n_dev)  # at least one element per shard
-        n -= n % n_dev
         kind = params.get("collective", "all_reduce")
-        schedule = params.get("schedule", "xla")
-        x = jnp.arange(n, dtype=jnp.float32)
-        sharded = jax.device_put(x, NamedSharding(mesh, P("x")))
-
-        if schedule == "xla":
-            if kind in ("all_reduce", "reduce_scatter"):
-                fn = jax.jit(lambda v: jnp.sum(v) * jnp.ones_like(v),
-                             in_shardings=NamedSharding(mesh, P("x")),
-                             out_shardings=NamedSharding(mesh, P("x") if kind == "reduce_scatter" else P()))
-            elif kind == "all_gather":
-                fn = jax.jit(lambda v: v + 1.0,
-                             in_shardings=NamedSharding(mesh, P("x")),
-                             out_shardings=NamedSharding(mesh, P()))
-            else:  # all_to_all / ppermute approximated by a resharding transpose
-                m2 = x.reshape(n_dev, n // n_dev)
-                sharded = jax.device_put(m2, NamedSharding(mesh, P("x", None)))
-                fn = jax.jit(lambda v: v.T,
-                             in_shardings=NamedSharding(mesh, P("x", None)),
-                             out_shardings=NamedSharding(mesh, P(None, "x")))
-        else:  # shardmap: explicit collectives; outputs flattened, out_specs P("x")
-            from jax.experimental.shard_map import shard_map
-
-            def body(v):
-                if kind == "all_reduce":
-                    return jax.lax.psum(v, "x")
-                if kind == "all_gather":
-                    return jax.lax.all_gather(v, "x", tiled=True).reshape(-1)
-                if kind == "reduce_scatter":
-                    return jax.lax.psum_scatter(v, "x", tiled=True)
-                if kind == "all_to_all":
-                    vv = v.reshape(n_dev, -1)
-                    out = jax.lax.all_to_all(vv, "x", split_axis=0, concat_axis=0, tiled=False)
-                    return out.reshape(-1)
-                # ppermute: ring shift
-                perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
-                return jax.lax.ppermute(v, "x", perm)
-
-            fn = jax.jit(
-                shard_map(body, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)
-            )
-
+        fn, sharded = collective(
+            mesh, kind, params.get("schedule", "xla"), _SIZES[params.get("payload", "1MB")]
+        )
+        n = sharded.size
         times = measure(fn, sharded, iters=ctx.iters, warmup=ctx.warmup)
         nbytes = 4.0 * n
         wire = {

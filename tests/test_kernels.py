@@ -51,6 +51,25 @@ def test_flash_attention_non_causal():
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-4, atol=2e-4)
 
 
+def test_flash_attention_ragged_is_refused():
+    """A sequence the blocks do not divide is an error, not a silent oracle."""
+    q = jnp.zeros((1, 200, 4, 64), jnp.float32)
+    with pytest.raises(ValueError, match="use_pallas=False"):
+        ops.flash_attention(q, q, q, causal=True, block_q=128, block_k=128)
+    out = ops.flash_attention(q, q, q, causal=True, block_q=128, block_k=128, use_pallas=False)
+    assert out.shape == q.shape
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
     "b,s,hq,hkv,dh,bk,lens",
@@ -139,7 +158,8 @@ def test_ssd_chunked_equals_naive_recurrence():
 @pytest.mark.parametrize(
     "e,c,d,f,bc,bf,bd",
     [(2, 128, 128, 128, 128, 128, 128), (4, 256, 512, 256, 128, 128, 256),
-     (8, 128, 256, 384, 64, 128, 128)],
+     (8, 128, 256, 384, 64, 128, 128),
+     (2, 200, 320, 300, 128, 128, 256)],  # ragged c/d/f: zero-padded to the blocks
 )
 def test_gmm_sweep(dtype, e, c, d, f, bc, bf, bd):
     ks = jax.random.split(KEY, 2)
@@ -330,7 +350,7 @@ def test_stream_runs_at_4m_cap():
     """The acceptance bar: cap >= 4M rows (output far past the 8 MB VMEM
     budget) streams byte-identically to the oracle."""
     cap = 4 * 1024 * 1024
-    assert 4 * (cap + SUB) * 4 > ops.VMEM_BUDGET_BYTES
+    assert ops.resident_bytes(4, cap) > ops.VMEM_BUDGET_BYTES
     _stream_case(65536, 0.9, cap, seed=13, block_n=16384)
 
 
