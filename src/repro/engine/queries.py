@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.engine import datagen, ops
 from repro.engine.table import Table
 from repro.kernels import ops as kops
@@ -382,12 +383,15 @@ def fused_query_serial(
     plan: ServingPlan, params: dict[str, Any], *, use_pallas: bool = True
 ) -> dict[str, jax.Array]:
     """One request through the single-program kernel — the serving oracle."""
-    pred_consts, agg_consts = plan.program(params)
-    out = kops.group_filter_agg(
-        plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
-        num_groups=plan.num_groups, use_pallas=use_pallas,
-    )
-    return plan.demux(out)
+    with tracing.span("serve.consts"):
+        pred_consts, agg_consts = plan.program(params)
+    with tracing.span("serve.launch"):
+        out = kops.group_filter_agg(
+            plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
+            num_groups=plan.num_groups, use_pallas=use_pallas,
+        )
+    with tracing.span("serve.demux"):
+        return plan.demux(out)
 
 
 def fused_query_batch(
@@ -405,11 +409,14 @@ def fused_query_batch(
     single-program path's; the additions inside one block's dot may be
     ordered differently).
     """
-    consts = [plan.program(p) for p in param_list]
-    pred_consts = jnp.stack([c[0] for c in consts])
-    agg_consts = jnp.stack([c[1] for c in consts])
-    out = kops.group_filter_agg_multi(
-        plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
-        num_groups=plan.num_groups, use_pallas=use_pallas,
-    )
-    return [plan.demux(out[b]) for b in range(len(param_list))]
+    with tracing.span("serve.consts"):
+        consts = [plan.program(p) for p in param_list]
+        pred_consts = jnp.stack([c[0] for c in consts])
+        agg_consts = jnp.stack([c[1] for c in consts])
+    with tracing.span("serve.launch"):
+        out = kops.group_filter_agg_multi(
+            plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
+            num_groups=plan.num_groups, use_pallas=use_pallas,
+        )
+    with tracing.span("serve.demux"):
+        return [plan.demux(out[b]) for b in range(len(param_list))]

@@ -193,6 +193,7 @@ def block_compact(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="block_compact",
     )(cols, mask)
     return out[:, :cap], cnt[0, 0]
 
@@ -340,6 +341,7 @@ def stream_chunk(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="block_compact",
     )(cols, mask, st, carry, out)
     return out, st, carry
 

@@ -285,5 +285,6 @@ def group_filter_agg(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="group_filter_agg",
     )(pred_ops, pred_consts, agg_ops, agg_consts, cols, keys)
     return out[:, :, : num_aggs + 1]

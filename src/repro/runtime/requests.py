@@ -47,17 +47,17 @@ class QueryRequest:
     query: str  # plan name: "q1" | "q6" | "q12"
     params: dict[str, Any]  # constants for queries.ServingPlan.program
     arrival_s: float = 0.0
+    admitted_s: float = 0.0  # time.perf_counter() when its server admitted it
 
 
 @dataclasses.dataclass
 class QueryCompletion:
-    """A finished query request with its result and latency breakdown."""
+    """A finished query request with its result and latency."""
 
     uid: int
     query: str
     result: dict[str, Any]
     latency_s: float  # arrival -> finish (includes queueing)
-    service_s: float  # kernel execution only
     batch_size: int = 1  # how many requests shared the scan
 
 

@@ -26,6 +26,7 @@ import sys
 import time
 from typing import Any, Callable
 
+from repro.core import tracing
 from repro.core.timing import block
 from repro.engine import queries as queries_mod
 from repro.runtime.loadgen import sample_params
@@ -72,6 +73,11 @@ class QueryServer:
     padded up to the next power of two (padding slots repeat the first
     request's constants and are discarded at demux) so the number of
     compiled executables stays logarithmic in ``max_batch``.
+
+    Each ``step`` is a tick of :data:`repro.core.tracing.RECORDER`: its
+    phases are ``serve.*`` spans, and its record counts the requests, the
+    padded kernel slots and how long the requests queued.  The first server
+    of a process turns garbage collections into ``serve.gc`` spans.
     """
 
     def __init__(
@@ -90,12 +96,14 @@ class QueryServer:
         self.use_pallas = use_pallas
         self.completed: list[QueryCompletion] = []
         self.kernel_calls = 0
+        tracing.RECORDER.hook_gc()
 
     # -- host scheduler ----------------------------------------------------
     def submit(self, req: QueryRequest) -> bool:
         """Admit or shed one request (bounded queue, never blocks)."""
         if req.query not in self.plans:
             raise KeyError(f"no serving plan for query {req.query!r}")
+        req.admitted_s = time.perf_counter()
         return self.queue.submit(req)
 
     def warmup(self, queries: list[str] | None = None) -> None:
@@ -118,19 +126,25 @@ class QueryServer:
                 size *= 2
 
     def _execute(self, batch: list[QueryRequest]) -> list[dict[str, Any]]:
-        """One kernel pass for ``batch`` (padded to a power of two)."""
+        """One kernel pass for ``batch`` (padded to a power of two); its
+        slots are counted in the open tick."""
         plan = self.plans[batch[0].query]
         self.kernel_calls += 1
+        tick = tracing.RECORDER.current()
         if len(batch) == 1:
+            tick.slots = 1
             result = queries_mod.fused_query_serial(
                 plan, batch[0].params, use_pallas=self.use_pallas
             )
-            block(result)
+            with tracing.span("serve.wait"):
+                block(result)
             return [result]
+        tick.slots = _pow2_at_least(len(batch))
         padded = [r.params for r in batch]
-        padded += [batch[0].params] * (_pow2_at_least(len(batch)) - len(batch))
+        padded += [batch[0].params] * (tick.slots - len(batch))
         results = queries_mod.fused_query_batch(plan, padded, use_pallas=self.use_pallas)
-        block(results)
+        with tracing.span("serve.wait"):
+            block(results)
         return results[: len(batch)]
 
     def step(self, now_fn: Callable[[], float] = time.perf_counter) -> list[QueryCompletion]:
@@ -140,26 +154,28 @@ class QueryServer:
         ``now_fn`` supplies the clock the trace's ``arrival_s`` offsets are
         on, so latency = finish - scheduled arrival (queueing included).
         """
-        head = self.queue.peek()
-        if head is None:
-            return []
-        batch = self.queue.take_matching(lambda r: r.query == head.query, self.max_batch)
-        t0 = now_fn()
-        results = self._execute(batch)
-        t1 = now_fn()
-        out = []
-        for req, result in zip(batch, results):
-            c = QueryCompletion(
-                uid=req.uid,
-                query=req.query,
-                result=result,
-                latency_s=t1 - min(req.arrival_s, t0),
-                service_s=t1 - t0,
-                batch_size=len(batch),
-            )
-            self.completed.append(c)
-            out.append(c)
-        return out
+        with tracing.RECORDER.tick() as tick:
+            with tracing.span("serve.coalesce"):
+                head = self.queue.peek()
+                if head is None:
+                    return []
+                batch = self.queue.take_matching(lambda r: r.query == head.query, self.max_batch)
+            tick.take(batch)
+            t0 = now_fn()
+            results = self._execute(batch)
+            t1 = now_fn()
+            out = []
+            for req, result in zip(batch, results):
+                c = QueryCompletion(
+                    uid=req.uid,
+                    query=req.query,
+                    result=result,
+                    latency_s=t1 - min(req.arrival_s, t0),
+                    batch_size=len(batch),
+                )
+                self.completed.append(c)
+                out.append(c)
+            return out
 
 
 def run_open_loop(server: QueryServer, trace: list[QueryRequest]) -> ServeReport:
