@@ -16,6 +16,11 @@ term products computed in-flight, and the grouped sums/counts accumulate in
 a VMEM tile — instead of the unfused jnp graph's one-HBM-pass-per-aggregate
 ``segment_sum`` plan.  Counts and integer-valued aggregates match the
 unfused results exactly; float sums agree to accumulation-order tolerance.
+
+The serving plans (``make_serving_plans``, ``fused_query_serial``/
+``fused_query_batch``) run the same kernel with constants that arrive per
+request: a call copies its constant tables to the device once and the
+kernel's output back once, and returns host numpy values.
 """
 from __future__ import annotations
 
@@ -157,7 +162,10 @@ def _q1_layout(lineitem: Table) -> tuple[jax.Array, jax.Array]:
     return cols, keys
 
 
-def _q1_demux(out: jax.Array) -> dict[str, jax.Array]:
+# The demux functions use only array methods, arithmetic and numpy
+# constants, so one function serves a device array (under ``jit`` or not)
+# and its host copy in numpy alike.
+def _q1_demux(out):
     """Q1 result dict from one [6, 6] kernel output row-block."""
     agg = {
         "sum_qty": out[:, 0],
@@ -167,7 +175,7 @@ def _q1_demux(out: jax.Array) -> dict[str, jax.Array]:
         "sum_disc": out[:, 4],
         "count": out[:, 5],
     }
-    cnt = jnp.maximum(agg["count"], 1.0)
+    cnt = agg["count"].clip(1.0)
     agg["avg_qty"] = agg["sum_qty"] / cnt
     agg["avg_price"] = agg["sum_base_price"] / cnt
     agg["avg_disc"] = agg["sum_disc"] / cnt
@@ -216,8 +224,8 @@ def _q6_layout(lineitem: Table) -> tuple[jax.Array, jax.Array]:
     return cols, keys
 
 
-def _q6_demux(out: jax.Array) -> dict[str, jax.Array]:
-    return {"revenue": out[0, 0], "rows": out[0, 1].astype(jnp.int32)}
+def _q6_demux(out):
+    return {"revenue": out[0, 0], "rows": out[0, 1].astype(np.int32)}
 
 
 def q6_fused(
@@ -280,13 +288,15 @@ def _q12_layout(lineitem: Table, orders: Table) -> tuple[jax.Array, jax.Array]:
     return cols, joined["l_shipmode"]
 
 
-def _q12_demux(out: jax.Array) -> dict[str, jax.Array]:
-    num_groups = len(datagen.SHIPMODE)
-    sel = jnp.zeros((num_groups,), jnp.float32).at[jnp.asarray(Q12_SHIPMODES)].set(1.0)
+# 1.0 for the shipmode groups Q12 selects, 0.0 for the others.
+_Q12_SELECT = np.isin(np.arange(len(datagen.SHIPMODE)), Q12_SHIPMODES).astype(np.float32)
+
+
+def _q12_demux(out):
     return {
-        "high_line_count": out[:, 0] * sel,
-        "low_line_count": out[:, 1] * sel,
-        "count": out[:, 2] * sel,
+        "high_line_count": out[:, 0] * _Q12_SELECT,
+        "low_line_count": out[:, 1] * _Q12_SELECT,
+        "count": out[:, 2] * _Q12_SELECT,
     }
 
 
@@ -325,9 +335,10 @@ class ServingPlan:
 
     ``cols``/``keys`` are the parameter-independent column layout (for Q12
     including the join, computed once); ``pred_ops``/``agg_ops`` the shared
-    opcode structure; ``program(params)`` builds one request's constant
-    tables; ``demux(out)`` turns one ``[G, A + 1]`` kernel output slot back
-    into the query's result dict.
+    opcode structure, on the device; ``program(params)`` builds one
+    request's constant tables in numpy; ``demux(out)`` turns one
+    ``[G, A + 1]`` kernel output slot, a device or a host array, back into
+    the query's result dict.
     """
 
     name: str
@@ -336,12 +347,12 @@ class ServingPlan:
     pred_ops: jax.Array
     agg_ops: jax.Array
     num_groups: int
-    program: Callable[[dict[str, Any]], tuple[jax.Array, jax.Array]]
-    demux: Callable[[jax.Array], dict[str, jax.Array]]
+    program: Callable[[dict[str, Any]], tuple[np.ndarray, np.ndarray]]
+    demux: Callable[[Any], dict[str, Any]]
 
 
-def _plan_program(program_fn) -> Callable[[dict[str, Any]], tuple[jax.Array, jax.Array]]:
-    def consts(params: dict[str, Any]) -> tuple[jax.Array, jax.Array]:
+def _plan_program(program_fn) -> Callable[[dict[str, Any]], tuple[np.ndarray, np.ndarray]]:
+    def consts(params: dict[str, Any]) -> tuple[np.ndarray, np.ndarray]:
         _, pred_consts, _, agg_consts = program_fn(**params)
         return pred_consts, agg_consts
 
@@ -370,8 +381,8 @@ def make_serving_plans(
             name=name,
             cols=cols,
             keys=keys,
-            pred_ops=pred_ops,
-            agg_ops=agg_ops,
+            pred_ops=jnp.asarray(pred_ops),
+            agg_ops=jnp.asarray(agg_ops),
             num_groups=num_groups,
             program=_plan_program(program_fn),
             demux=demux,
@@ -381,15 +392,23 @@ def make_serving_plans(
 
 def fused_query_serial(
     plan: ServingPlan, params: dict[str, Any], *, use_pallas: bool = True
-) -> dict[str, jax.Array]:
-    """One request through the single-program kernel — the serving oracle."""
+) -> dict[str, np.ndarray]:
+    """One request through the single-program kernel — the serving oracle.
+
+    The constant tables go to the device in one explicit copy and the
+    kernel's output comes back in another, inside ``serve.wait``, the only
+    place the host waits for the device; the result holds host numpy
+    values.
+    """
     with tracing.span("serve.consts"):
-        pred_consts, agg_consts = plan.program(params)
+        pred_consts, agg_consts = jax.device_put(plan.program(params))
     with tracing.span("serve.launch"):
         out = kops.group_filter_agg(
             plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
             num_groups=plan.num_groups, use_pallas=use_pallas,
         )
+    with tracing.span("serve.wait"):
+        out = jax.device_get(out)
     with tracing.span("serve.demux"):
         return plan.demux(out)
 
@@ -399,11 +418,13 @@ def fused_query_batch(
     param_list: list[dict[str, Any]],
     *,
     use_pallas: bool = True,
-) -> list[dict[str, jax.Array]]:
+) -> list[dict[str, np.ndarray]]:
     """Scan sharing: N same-shape requests, ONE kernel pass over the data.
 
     Each request's constants become one slot of the batched SMEM program
-    tables; results demultiplex per request and equal
+    tables, stacked on the host and copied to the device at once; the
+    ``[B, G, A + 1]`` output comes back in one copy and demultiplexes per
+    request in numpy.  Results are host numpy values and equal
     ``fused_query_serial`` on the same constants: counts exactly, float
     sums within a few ulps (the per-program block order is the
     single-program path's; the additions inside one block's dot may be
@@ -411,12 +432,15 @@ def fused_query_batch(
     """
     with tracing.span("serve.consts"):
         consts = [plan.program(p) for p in param_list]
-        pred_consts = jnp.stack([c[0] for c in consts])
-        agg_consts = jnp.stack([c[1] for c in consts])
+        pred_consts, agg_consts = jax.device_put(
+            (np.stack([c[0] for c in consts]), np.stack([c[1] for c in consts]))
+        )
     with tracing.span("serve.launch"):
         out = kops.group_filter_agg_multi(
             plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
             num_groups=plan.num_groups, use_pallas=use_pallas,
         )
+    with tracing.span("serve.wait"):
+        out = jax.device_get(out)
     with tracing.span("serve.demux"):
         return [plan.demux(out[b]) for b in range(len(param_list))]

@@ -67,8 +67,10 @@ _FLOAT_MAX = float(np.finfo(np.float32).max)
 
 
 # ---------------------------------------------------------------------------
-# Program encoding: tiny int/float tables a query builds once at trace time.
-def encode_predicates(preds) -> tuple[jax.Array, jax.Array]:
+# Program encoding: tiny int/float tables, built on the host.  They are numpy
+# arrays: a caller places them on the device (or hands them to ``jit``, which
+# makes them trace-time constants).
+def encode_predicates(preds) -> tuple[np.ndarray, np.ndarray]:
     """preds: sequence of ("range", col, lo, hi) | ("lt", col_a, col_b).
 
     ``lo``/``hi`` may be ``None`` for an open bound.  Returns
@@ -94,10 +96,7 @@ def encode_predicates(preds) -> tuple[jax.Array, jax.Array]:
     if not ops:
         ops.append((PRED_RANGE, 0, 0))
         consts.append((_FLOAT_MIN, _FLOAT_MAX))
-    return (
-        jnp.asarray(ops, jnp.int32),
-        jnp.asarray(consts, jnp.float32),
-    )
+    return np.asarray(ops, np.int32), np.asarray(consts, np.float32)
 
 
 _TERM_CODES = {
@@ -109,7 +108,7 @@ _TERM_CODES = {
 }
 
 
-def encode_aggregates(aggs) -> tuple[jax.Array, jax.Array]:
+def encode_aggregates(aggs) -> tuple[np.ndarray, np.ndarray]:
     """aggs: sequence of aggregates; each is a sequence of <= MAX_TERMS terms.
 
     A term is ("col", i) | ("one_minus", i) | ("one_plus", i)
@@ -132,7 +131,7 @@ def encode_aggregates(aggs) -> tuple[jax.Array, jax.Array]:
             ops[a, 2 * t + 1] = int(term[1])
             if kind in (TERM_LE, TERM_GT):
                 consts[a, t] = float(term[2])
-    return jnp.asarray(ops), jnp.asarray(consts)
+    return ops, consts
 
 
 # ---------------------------------------------------------------------------

@@ -56,7 +56,7 @@ class QueryCompletion:
 
     uid: int
     query: str
-    result: dict[str, Any]
+    result: dict[str, Any]  # host numpy values
     latency_s: float  # arrival -> finish (includes queueing)
     batch_size: int = 1  # how many requests shared the scan
 

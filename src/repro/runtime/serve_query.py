@@ -8,9 +8,10 @@ completions — but the batching axis differs: where decode slots batch
 *positions* of independent sequences, the query server batches *programs*
 of one query shape.  N pending requests with different predicate constants
 coalesce into one SMEM-program batch (`kernels.ops.group_filter_agg_multi`)
-over a single pass through the column data; per-request results come back
-de-multiplexed and equal serial execution: counts exactly, float sums
-within a few ulps (tests/test_serving.py).
+over a single pass through the column data; the kernel's output comes back
+to the host in one copy, and per-request results, host numpy values, are
+de-multiplexed from it and equal serial execution: counts exactly, float
+sums within a few ulps (tests/test_serving.py).
 
 Latency is measured from each request's *scheduled* open-loop arrival time
 — queueing delay included — so an overloaded server shows up as tail
@@ -27,7 +28,6 @@ import time
 from typing import Any, Callable
 
 from repro.core import tracing
-from repro.core.timing import block
 from repro.engine import queries as queries_mod
 from repro.runtime.loadgen import sample_params
 from repro.runtime.requests import QueryCompletion, QueryRequest, RequestQueue
@@ -113,38 +113,26 @@ class QueryServer:
         for name in queries or list(self.plans):
             plan = self.plans[name]
             params = sample_params(name, random.Random(0))
-            size = 1
+            queries_mod.fused_query_serial(plan, params, use_pallas=self.use_pallas)
+            size = 2
             while size <= self.max_batch:
-                if size == 1:
-                    block(queries_mod.fused_query_serial(plan, params, use_pallas=self.use_pallas))
-                else:
-                    block(
-                        queries_mod.fused_query_batch(
-                            plan, [params] * size, use_pallas=self.use_pallas
-                        )
-                    )
+                queries_mod.fused_query_batch(plan, [params] * size, use_pallas=self.use_pallas)
                 size *= 2
 
     def _execute(self, batch: list[QueryRequest]) -> list[dict[str, Any]]:
         """One kernel pass for ``batch`` (padded to a power of two); its
-        slots are counted in the open tick."""
+        slots are counted in the open tick.  The results are on the host:
+        the plan functions wait for the kernel in ``serve.wait``."""
         plan = self.plans[batch[0].query]
         self.kernel_calls += 1
         tick = tracing.RECORDER.current()
         if len(batch) == 1:
             tick.slots = 1
-            result = queries_mod.fused_query_serial(
-                plan, batch[0].params, use_pallas=self.use_pallas
-            )
-            with tracing.span("serve.wait"):
-                block(result)
-            return [result]
+            return [queries_mod.fused_query_serial(plan, batch[0].params, use_pallas=self.use_pallas)]
         tick.slots = _pow2_at_least(len(batch))
         padded = [r.params for r in batch]
         padded += [batch[0].params] * (tick.slots - len(batch))
         results = queries_mod.fused_query_batch(plan, padded, use_pallas=self.use_pallas)
-        with tracing.span("serve.wait"):
-            block(results)
         return results[: len(batch)]
 
     def step(self, now_fn: Callable[[], float] = time.perf_counter) -> list[QueryCompletion]:

@@ -14,6 +14,7 @@ import pytest
 from repro.core import config as config_mod
 from repro.core.metrics import Samples, compute_metrics
 from repro.engine import datagen, queries
+from repro.kernels.group_filter_agg import encode_aggregates, encode_predicates
 from repro.runtime.loadgen import arrival_times, generate_trace, sample_params
 from repro.runtime.requests import QueryRequest, RequestQueue
 from repro.runtime.serve_query import (
@@ -40,10 +41,15 @@ def _assert_same_result(want, got, label):
 
 
 @pytest.fixture(scope="module")
-def plans():
+def tables():
     li = datagen.lineitem(jax.random.PRNGKey(0), rows=ROWS)
     od = datagen.orders(jax.random.PRNGKey(1), rows=ROWS // 4)
-    return queries.make_serving_plans(li, od)
+    return li, od
+
+
+@pytest.fixture(scope="module")
+def plans(tables):
+    return queries.make_serving_plans(*tables)
 
 
 # -- open-loop load generation -------------------------------------------------
@@ -176,6 +182,82 @@ def test_micro_batch_byte_equals_serial(plans, qname, use_pallas):
     for params, got in zip(param_list, batched):
         want = queries.fused_query_serial(plans[qname], params, use_pallas=use_pallas)
         _assert_same_result(want, got, qname)
+
+
+def _fused(qname, tables, params):
+    """The ``q*_fused`` device path: the demux runs on device arrays."""
+    li, od = tables
+    args = (li, od) if qname == "q12" else (li,)
+    return queries.FUSED_QUERIES[qname](*args, **params)
+
+
+def _is_host_value(x) -> bool:
+    return isinstance(x, (np.ndarray, np.generic))
+
+
+@pytest.mark.parametrize("qname", ["q1", "q6", "q12"])
+@pytest.mark.parametrize("size", [1, 2, 3, 8])
+def test_batch_equals_the_fused_device_path(tables, plans, qname, size):
+    """The host demux of a batch gives the device demux's answers; 3
+    requests are padded to 4 slots as the server pads them."""
+    rng = random.Random(size)
+    param_list = [sample_params(qname, rng) for _ in range(size)]
+    slots = 1 << (size - 1).bit_length()
+    padded = param_list + [param_list[0]] * (slots - size)
+    got = queries.fused_query_batch(plans[qname], padded)[:size]
+    if size == 1:
+        got.append(queries.fused_query_serial(plans[qname], param_list[0]))
+        param_list = param_list * 2
+    for params, result in zip(param_list, got, strict=True):
+        _assert_same_result(_fused(qname, tables, params), result, (qname, params))
+        assert all(_is_host_value(v) for v in result.values()), result
+
+
+def test_serving_results_are_host_values(plans):
+    rng = random.Random(2)
+    server = QueryServer(plans, max_batch=8)
+    for i, name in enumerate(["q1", "q1", "q6", "q12"]):
+        server.submit(QueryRequest(uid=i, query=name, params=sample_params(name, rng)))
+    done = []
+    while len(server.queue):
+        done += server.step()
+    assert sorted(c.uid for c in done) == [0, 1, 2, 3]
+    for c in done:
+        assert all(_is_host_value(v) for v in c.result.values()), (c.query, c.result)
+
+
+def test_constant_tables_are_built_on_the_host():
+    pred = encode_predicates([("range", 0, 1.0, 2.0), ("lt", 1, 2)])
+    agg = encode_aggregates([[("col", 1), ("le", 2, 0.5)]])
+    for table in (*pred, *agg, *queries.q1_program(), *queries.q12_program(1995)):
+        assert isinstance(table, np.ndarray), type(table)
+
+
+def test_plan_opcode_tables_stay_on_the_device(plans):
+    for plan in plans.values():
+        assert isinstance(plan.pred_ops, jax.Array) and isinstance(plan.agg_ops, jax.Array)
+        consts = plan.program(sample_params(plan.name, random.Random(0)))
+        assert all(isinstance(c, np.ndarray) for c in consts)
+
+
+@pytest.mark.parametrize("qname", ["q1", "q6", "q12"])
+def test_a_warm_tick_copies_only_explicitly(plans, qname):
+    """A warm tick of three requests makes one explicit copy of the
+    constant tables to the device and one of the output back: under a
+    guard that refuses implicit host-device copies it still serves."""
+    server = QueryServer(plans, max_batch=8)
+    rng = random.Random(4)
+
+    def tick():
+        for i in range(3):
+            server.submit(QueryRequest(uid=i, query=qname, params=sample_params(qname, rng)))
+        return server.step()
+
+    tick()  # compiles the 4-slot program
+    with jax.transfer_guard("disallow"):
+        done = tick()
+    assert [c.uid for c in done] == [0, 1, 2]
+    assert server.kernel_calls == 2
 
 
 def test_server_batched_results_byte_equal_serial(plans):

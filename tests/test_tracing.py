@@ -18,7 +18,7 @@ from repro.runtime.requests import QueryCompletion, QueryRequest
 from repro.runtime.serve_query import QueryServer
 
 ROWS = 2_000
-PHASES = ["serve.coalesce", "serve.consts", "serve.launch", "serve.demux", "serve.wait"]
+PHASES = ["serve.coalesce", "serve.consts", "serve.launch", "serve.wait", "serve.demux"]
 Q6 = [{"year": 1993 + i % 5, "discount": 0.02 + 0.01 * (i % 7), "qty": 24.0 + i % 2} for i in range(8)]
 
 
@@ -63,6 +63,8 @@ def test_step_spans_are_named_nested_and_in_order(server, tmp_path, batch):
     assert args["tick"] == tick.tick and args["query"] == "q6"
     assert args["uids"] == str([100 + i for i in range(batch)])
     assert [s[0] for s in spans[1:]] == PHASES
+    # One wait a tick: serve_host_ms subtracts every wait inside a tick.
+    assert [s[0] for s in spans].count("serve.wait") == 1
     for phase, a, b, phase_args in spans[1:]:
         assert start <= a <= b <= end, phase
         assert phase_args["tick"] == tick.tick, phase
