@@ -24,7 +24,7 @@ conditional counts exactly, float sums within a relative deviation of
 ``RTOL``.  The unfused plans are held to the same exact counts; their float
 sums accumulate in f32 (``segment_sum``), so their deviation is reported,
 not bounded.  Compacted rows must match bit for bit.  The four-chip run
-builds the network task's mesh over four devices and checks all_reduce and
+builds the 1-D mesh of ``launch.mesh.mesh_1d`` over four devices and checks all_reduce and
 all_gather under ``schedule=xla`` and ``schedule=shardmap`` at 32 MB against
 numpy.
 
@@ -304,10 +304,11 @@ def _one_chip(seed: int) -> list[str]:
 def _four_chips() -> list[str]:
     import numpy as np
 
+    from repro.launch.mesh import mesh_1d
     from repro.tasks import network
 
     failed: list[str] = []
-    mesh = network.mesh_1d()
+    mesh = mesh_1d()
     _require(mesh.size == 4, mesh.size)
 
     def run(kind, schedule):

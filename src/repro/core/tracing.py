@@ -4,12 +4,15 @@ bounded ring of the query server's ticks and of garbage collections.
 :func:`span` is a ``jax.profiler.TraceAnnotation``: in a profiled run it
 lies on the trace's clock beside the device's ops, with its arguments as
 the event's stats; with no profiler running it is an empty context.  Span
-names start with ``serve.``.  A span opened inside a tick carries the
-tick's id and, once the tick has taken its requests, their query and uids.
+names start with ``serve.`` (the query server) or ``pushdown.`` (the
+sharded scan, ``engine.ops.ShardScan``).  A span opened inside a tick
+carries the tick's id and, once the tick has taken its requests, their
+query and uids.
 
 :data:`RECORDER` keeps, for the whole process, the last :data:`RING` ticks
-(:class:`Tick`) and garbage collections (:class:`GcPause`).  The trace and
-the ring are the only outputs: nothing is written to a file.
+(:class:`Tick`) and garbage collections (:class:`GcPause`).  Each sharded
+scan keeps its own :class:`Exchange` counters.  The trace, the ring and
+the counters are the only outputs: nothing is written to a file.
 """
 from __future__ import annotations
 
@@ -61,6 +64,17 @@ class GcPause:
     generation: int
     start_s: float
     end_s: float
+
+
+@dataclasses.dataclass
+class Exchange:
+    """Counters of a sharded scan: requests launched, the bytes the
+    consumer received from the other owners over the chips' links, and
+    owners whose qualifying rows overflowed their capacity."""
+
+    requests: int = 0
+    bytes_exchanged: int = 0
+    overflows: int = 0
 
 
 class Recorder:

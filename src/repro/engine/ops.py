@@ -11,11 +11,15 @@ TPU-idiomatic choices:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import tracing
 from repro.engine.table import Table
 
 
@@ -80,6 +84,94 @@ def compact(
 
 def _bmask(m: jax.Array, ndim: int) -> jax.Array:
     return m.reshape(m.shape + (1,) * (ndim - 1))
+
+
+# ---------------------------------------------------------------------------
+# Pushdown across chips.
+def shard_compact(
+    table: Table, lo, hi, cap: int, mesh: Mesh, axis: str = "x", use_pallas: bool = True,
+) -> tuple[Table, jax.Array]:
+    """Predicate pushdown over a table whose rows are sharded over ``axis``
+    of ``mesh`` in table order: each owner compacts its own rows with
+    ``lo <= l_shipdate < hi`` (:func:`compact`, ``cap`` rows), and only the
+    capacity buffers and the counts cross chips, to the consumer, the owner
+    of shard 0.  Each other owner's buffer and count reach the consumer
+    once, in a ``ppermute`` of their own.
+
+    Returns ``(slots, counts)``, each sharded over ``axis`` in blocks of
+    ``S`` rows; the consumer's block, the first, is the result.  Row ``s``
+    of a column of ``slots`` (``[S * S, cap]``) holds owner ``s``'s
+    compacted rows in table order, zero past its count, and ``counts[s]``
+    (``[S * S]``) is its mask population: an owner whose count exceeds
+    ``cap`` has overflowed and kept its first ``cap`` rows.  The other
+    chips' blocks lead with their own buffer and count and are not part of
+    the result.
+    """
+    s = mesh.shape[axis]
+
+    def to_consumer(x):
+        return jnp.stack([x] + [jax.lax.ppermute(x, axis, [(k, 0)]) for k in range(1, s)])
+
+    def owner(t, lo, hi):
+        out, cnt = compact(t, pred_between(t["l_shipdate"], lo, hi), cap, use_pallas=use_pallas)
+        return jax.tree.map(to_consumer, (out, cnt))
+
+    # The kernels' out_shape carries no varying-axes type: check_vma off.
+    return jax.shard_map(
+        owner, mesh=mesh, in_specs=(P(axis), P(), P()), out_specs=P(axis), check_vma=False
+    )(table, lo, hi)
+
+
+def _scan(table, lo, hi, *, cap, mesh, axis, use_pallas):
+    """shard_compact, and per chip its block's (total, overflowed owners)."""
+    slots, counts = shard_compact(table, lo, hi, cap, mesh, axis, use_pallas)
+    s = mesh.shape[axis]
+    blocks = counts.reshape(s, s)
+    return slots, counts, jnp.stack([jnp.sum(blocks, 1), jnp.sum(blocks > cap, 1)], axis=1)
+
+
+class ShardScan:
+    """The sharded pushdown plan as its caller drives it: one jitted
+    :func:`shard_compact` for a mesh and a per-owner capacity.
+
+    A call launches one scan (span ``pushdown.launch``), waits for the
+    total count and the number of overflowed owners, the only values the
+    host reads (span ``pushdown.count``), and adds the request, the
+    consumer's inbound bytes and the overflows to :attr:`exchange`.
+    """
+
+    def __init__(self, mesh: Mesh, cap: int, use_pallas: bool = True):
+        (axis,) = mesh.axis_names  # a 1-D mesh of owners
+        self.mesh, self.cap, self.axis = mesh, cap, axis
+        self.consumer = mesh.devices.flat[0]
+        self.exchange = tracing.Exchange()
+        self._run = jax.jit(
+            functools.partial(_scan, cap=cap, mesh=mesh, axis=axis, use_pallas=use_pallas),
+            out_shardings=NamedSharding(mesh, P(axis)),
+        )
+
+    def bytes_per_request(self, table: Table) -> int:
+        """The consumer's inbound bytes: every other owner's capacity
+        buffer and count."""
+        row = sum(table[n].dtype.itemsize for n in table.names)
+        return (self.mesh.shape[self.axis] - 1) * (self.cap * row + 4)
+
+    def __call__(self, table: Table, lo: float, hi: float) -> tuple[Table, jax.Array, int]:
+        """``(slots, counts, total)``: the consumer's ``[S, cap]`` block of
+        each column and its ``[S]`` per-owner counts, left on the consumer,
+        and the counts' sum on the host."""
+        with tracing.span("pushdown.launch"):
+            slots, counts, summary = self._run(table, np.float32(lo), np.float32(hi))
+        with tracing.span("pushdown.count"):
+            total, overflows = (int(v) for v in jax.device_get(self._on_consumer(summary))[0])
+        self.exchange.requests += 1
+        self.exchange.bytes_exchanged += self.bytes_per_request(table)
+        self.exchange.overflows += overflows
+        slots = Table({n: self._on_consumer(c) for n, c in slots.columns.items()})
+        return slots, self._on_consumer(counts), total
+
+    def _on_consumer(self, col: jax.Array) -> jax.Array:
+        return next(sh.data for sh in col.addressable_shards if sh.device == self.consumer)
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,14 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     return jax.make_mesh((data, model), ("data", "model"))
 
 
+def mesh_1d() -> Mesh:
+    """A 1-D mesh ``x`` over every device of the process, in
+    ``jax.devices()`` order: the network collectives and the sharded
+    pushdown scan run on it."""
+    devs = jax.devices()
+    return Mesh(np.array(devs).reshape(len(devs)), ("x",))
+
+
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class Rules:

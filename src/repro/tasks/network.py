@@ -25,16 +25,9 @@ from repro.core.metrics import Samples
 from repro.core.registry import register
 from repro.core.task import Task, TaskContext
 from repro.core.timing import measure
+from repro.launch.mesh import mesh_1d
 
 _SIZES = {"32KB": 1 << 13, "1MB": 1 << 18, "32MB": 1 << 23, "256MB": 1 << 26}  # f32 counts
-
-
-def mesh_1d() -> Mesh:
-    """A 1-D mesh ``x`` over every device of the process."""
-    import numpy as np
-
-    devs = jax.devices()
-    return Mesh(np.array(devs).reshape(len(devs)), ("x",))
 
 
 def collective(mesh: Mesh, kind: str, schedule: str, n: int):

@@ -1,30 +1,24 @@
 """Predicate pushdown module task (paper §3.5.1, Fig. 13).
 
-Disaggregated-storage scan mapped to the pod: table rows live sharded
-across "storage owner" devices. Two plans for `SELECT ... WHERE pred`:
+Three plans for ``SELECT ... WHERE lo <= l_shipdate < hi`` over lineitem:
 
-  baseline — fetch-then-filter: all rows move to the consumer (a full
-             all-gather of every scanned column), predicate evaluated after
-             the move. Bytes on the wire = full table.
-  pushdown — filter at the data owners (shard_map local predicate +
-             fixed-capacity compact), only qualifying rows move. Bytes on
-             the wire ~ selectivity x table (+ capacity padding).
-             `impl=kernel` swaps the nonzero+gather compaction for the
-             fused `block_compact` Pallas kernel (one pass: per-block mask
-             count + prefix-offset scatter); `impl=jnp` keeps the unfused
-             plan. `impl` is ignored by the other plans.  Capacity is
-             HBM-bounded, not VMEM-bounded: past the resident kernel's
-             VMEM budget the wrapper streams compacted tiles to an HBM
-             output with double-buffered DMA, so the kernel rows run at
-             scale 1.0 / selectivity 0.5 (cap 4.5M rows) too.
-  pushdown_kernel — fully fused filter+aggregate at the owners (the Q6
-             filter_agg kernel): zero row movement, only the aggregate
-             travels.
+  baseline — fetch-then-filter: a copy of every scanned column stands for
+             the move of the whole table to the consumer, and the predicate
+             runs after it.  Bytes moved = the scanned columns.
+  pushdown — filter at the storage owners: the scanned columns are
+             row-sharded over a 1-D mesh of every device of the process
+             (``launch.mesh.mesh_1d``), each owner compacts its own
+             qualifying rows into a buffer of fixed capacity, and only the
+             buffers and counts travel to the consumer, the owner of shard
+             0 (``engine.ops.ShardScan``).  Bytes moved = every owner's
+             buffer.  ``impl=kernel`` compacts with the ``block_compact``
+             Pallas kernel (HBM-streaming past its VMEM budget),
+             ``impl=jnp`` with ``nonzero`` + gather.  On one device the
+             consumer owns the whole table and nothing crosses chips.
+  pushdown_kernel — filter and aggregate at the data in one pass (the Q6
+             ``filter_agg`` kernel): only the aggregate travels.
 
-On >1 device both plans execute their real collectives; on one device the
-data movement collapses but the compute asymmetry (and the dry-run's wire
-bytes, which benchmarks/bench_pushdown.py reports) still distinguishes the
-plans. Params mirror the paper: scale x selectivity x lanes.
+``impl`` is ignored by the other plans.  Params: scale x selectivity.
 """
 from __future__ import annotations
 
@@ -32,12 +26,14 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.metrics import Samples
 from repro.core.registry import register
 from repro.core.task import Task, TaskContext
 from repro.core.timing import measure
 from repro.engine import datagen, ops
+from repro.launch.mesh import mesh_1d
 
 _SCALES = {"0.01": 60_000, "0.1": 600_000, "1.0": 6_000_000}
 
@@ -84,7 +80,6 @@ class PushdownTask(Task):
         use_kernel = params.get("impl", "jnp") == "kernel"
         lo, hi = _pred_bounds(sel)
         n = table.num_rows
-        cap = max(1024, int(1.5 * sel * n))
         cols = ("l_shipdate", "l_extendedprice", "l_discount", "l_quantity")
         scanned = table.select(*cols)
 
@@ -101,26 +96,25 @@ class PushdownTask(Task):
             moved_bytes = scanned.nbytes()
             moved_bytes_exact = moved_bytes  # every row moves, no padding
         elif plan == "pushdown":
-            # filter at the owners, move only qualifying rows (capacity-bounded)
-            @jax.jit
-            def fn(t):
-                mask = ops.pred_between(t["l_shipdate"], lo, hi)
-                out, cnt = ops.compact(t, mask, cap, use_pallas=use_kernel)
-                # compact already returns the true count; slots < cnt are the
-                # qualifying rows (masking on value != 0 would silently drop
-                # genuine zero-valued qualifying rows).
-                valid = jnp.arange(cap) < cnt
-                return ops.masked_sum(out["l_extendedprice"], valid), cnt
+            # filter at the owners, move only their capacity-bounded buffers
+            if "mesh" not in ctx.scratch:
+                ctx.scratch["mesh"] = mesh_1d()  # every device of the process
+            mesh = ctx.scratch["mesh"]
+            owners = mesh.size
+            owner_cap = max(1024, int(1.5 * sel * n / owners))
+            scan = ops.ShardScan(mesh, owner_cap, use_pallas=use_kernel)
+            sharded = jax.device_put(scanned, NamedSharding(mesh, P(scan.axis)))
 
-            times = measure(fn, scanned, iters=ctx.iters, warmup=ctx.warmup)
-            # Provisioned wire traffic: the capacity-bounded buffer always
-            # travels whole.  The exact column below charges only rows that
-            # actually qualified, so Fig. 13 can show both.
-            moved_bytes = cap * 16  # 4 cols x 4 B per provisioned slot
-            qualifying = int(
-                ops.masked_count(ops.pred_between(scanned["l_shipdate"], lo, hi))
-            )
-            moved_bytes_exact = min(qualifying, cap) * 16
+            def fn(t):
+                return scan(t, lo, hi)
+
+            times = measure(fn, sharded, iters=ctx.iters, warmup=ctx.warmup)
+            _, counts, _ = fn(sharded)
+            # Provisioned traffic: every owner's buffer travels whole.  The
+            # exact column charges only the rows that qualified, so Fig. 13
+            # can show both.
+            moved_bytes = owners * owner_cap * 16  # 4 cols x 4 B per provisioned slot
+            moved_bytes_exact = sum(min(int(c), owner_cap) for c in jax.device_get(counts)) * 16
         else:  # pushdown_kernel: fused Pallas filter+aggregate, zero row movement
             from repro.kernels import ops as kops
 

@@ -103,3 +103,27 @@ def test_filter_agg_compiles(shape):
         lambda c: filter_scan.filter_agg(c, 8035.0, 8287.6, -1.0, 1.0, block_n=GROUP_BLOCK),
         shape((4, _padded(GROUP_BLOCK)), jnp.float32),
     )
+
+
+def test_shard_compact_compiles_for_four_chips(topo, monkeypatch):
+    """The sharded pushdown scan on a 2x2 mesh: the streaming kernel on
+    every owner and one collective-permute pair a round into chip 0."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.engine import ops as engine_ops
+    from repro.engine.table import Table
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)  # compile for the chip, not the interpreter
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("x",))
+    rows, cap = 4 * 2 * CHUNK, 600_000  # two chunks an owner, past the VMEM budget
+    cols = ("l_discount", "l_extendedprice", "l_quantity", "l_shipdate")
+    table = Table({c: jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=NamedSharding(mesh, P("x")))
+                   for c in cols})
+    bound = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, P()))
+    scan = functools.partial(engine_ops.shard_compact, cap=cap, mesh=mesh)
+    text = jax.jit(scan).lower(table, bound, bound).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "collective-permute" in text and "all-gather" not in text
