@@ -57,7 +57,10 @@ def compact(
     (one pass: per-block mask count + prefix-offset scatter) instead of
     ``nonzero`` + one gather per column; only 1-D columns whose values are
     exactly representable in f32 survive the kernel's column matrix, so the
-    caller selects the scanned columns first (the pushdown plan does).
+    caller selects the scanned columns first (the pushdown plan does).  The
+    kernel copies +0 and finite values with ``2^-103 <= |x| < 2^127`` bit
+    for bit (the lineitem columns lie well inside); ``-0.0`` comes back as
+    ``+0.0`` and a NaN or inf poisons the slots of its 512-row sub-tile.
     ``stream`` passes through to the kernel wrapper: ``"auto"`` keeps small
     capacities on the VMEM-resident kernel and switches to the HBM-streaming
     kernel once the output buffer would blow the VMEM budget, so

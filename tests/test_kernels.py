@@ -330,6 +330,7 @@ def _stream_case(n, sel, cap, seed, c=4, **kw):
 
 
 from repro.kernels.block_compact import (  # noqa: E402 - grouped with its tests
+    _PER_STEP,
     SUB,
     block_compact_stream,
     stream_chunk,
@@ -430,3 +431,89 @@ def test_auto_dispatch_streams_past_vmem_budget():
         exp, ecnt = ref.block_compact_ref(cols, mask, cap)
         assert int(cnt) == int(ecnt)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
+
+
+# ---------------------------------------------------------------------------
+# block_compact, bit for bit: the three-plane bf16 pack and the lane-rotation
+# placement at the offsets and values where they could go wrong.
+# Blocks of one loop iteration and two sub-tiles more, two blocks.
+_EXACT_BLOCK = (_PER_STEP + 2) * SUB
+_EXACT_N = 2 * _EXACT_BLOCK
+
+
+def _finite_bits(key, shape):
+    """Random f32 bit patterns with 2^-103 <= |x| < 2^127, both signs."""
+    k1, k2 = jax.random.split(key)
+    exp = jax.random.randint(k1, shape, 127 - 103, 127 + 127).astype(jnp.uint32)
+    man = jax.random.bits(k2, shape, jnp.uint32) & jnp.uint32(0x807FFFFF)
+    return jax.lax.bitcast_convert_type(man | (exp << 23), jnp.float32)
+
+
+def _lineitem_values(key, n):
+    """The scanned lineitem columns: whole-day dates, integer quantities,
+    two-decimal prices and discounts."""
+    k = jax.random.split(key, 4)
+    days = jax.random.randint(k[0], (n,), 8036, 10561).astype(jnp.float32)
+    qty = jax.random.randint(k[1], (n,), 1, 51).astype(jnp.float32)
+    price = jax.random.randint(k[2], (n,), 90000, 10494951).astype(jnp.float32) / 100.0
+    disc = jax.random.randint(k[3], (n,), 0, 11).astype(jnp.float32) / 100.0
+    return jnp.stack([disc, price, qty, days])
+
+
+def _sub_tile_mask(key, counts, n):
+    """``counts[s]`` qualifiers at random rows of sub-tile ``s``; the rest
+    of the sub-tiles qualify at random, about half their rows."""
+    rows = []
+    for s in range(n // SUB):
+        k = jax.random.fold_in(key, s)
+        if s < len(counts):
+            order = jax.random.permutation(k, SUB)
+            rows.append(order < counts[s])
+        else:
+            rows.append(jax.random.uniform(k, (SUB,)) < 0.5)
+    return jnp.concatenate(rows).astype(jnp.int32).reshape(1, n)
+
+
+# name: (qualifiers in the leading sub-tiles, cap or None for all rows, values)
+_EXACT_CASES = {
+    "fill0": ([0], None, "bits"),  # the second sub-tile starts at offset 0
+    "fill1": ([1], None, "bits"),
+    "fill127": ([127], None, "bits"),  # resident skew 127
+    "fill511": ([SUB - 1], None, "bits"),  # streaming fill SUB - 1
+    "full_and_empty": ([SUB, 0, SUB, 5, 0], None, "bits"),
+    "cap_in_sub_tile": ([300, 200], 700, "bits"),  # cap ends inside sub-tile 2
+    "lineitem": ([], None, "lineitem"),
+}
+
+
+def _run_compact(kernel, cols, mask, cap):
+    if kernel == "resident":
+        return ops.block_compact(cols, mask, cap, block_n=_EXACT_BLOCK, stream="never")
+    if kernel == "stream":
+        return block_compact_stream(cols, mask, cap, block_n=_EXACT_BLOCK, interpret=True)
+    # chunked: chunks of 3 sub-tiles, so the carry crosses chunk boundaries
+    return ops.block_compact(
+        cols, mask, cap, block_n=SUB, stream="always", chunk_n=3 * SUB
+    )
+
+
+@pytest.mark.parametrize("kernel", ["resident", "stream", "chunked"])
+@pytest.mark.parametrize("case", sorted(_EXACT_CASES))
+def test_block_compact_bit_exact(kernel, case):
+    """Every slot equals the oracle's bits (int32 views), for offsets at
+    the lane and tile edges, empty and full sub-tiles, a cap inside a
+    sub-tile, values across the whole exact range and lineitem values."""
+    counts, cap, values = _EXACT_CASES[case]
+    k = jax.random.fold_in(KEY, 1000 + sorted(_EXACT_CASES).index(case))
+    if values == "bits":
+        cols = _finite_bits(k, (4, _EXACT_N))
+    else:
+        cols = _lineitem_values(k, _EXACT_N)
+    mask = _sub_tile_mask(jax.random.fold_in(k, 1), counts, _EXACT_N)
+    cap = cap or _EXACT_N
+    out, cnt = _run_compact(kernel, cols, mask, cap)
+    exp, ecnt = ref.block_compact_ref(cols, mask, cap)
+    assert int(cnt) == int(ecnt) == int(np.asarray(mask).sum())
+    np.testing.assert_array_equal(
+        np.asarray(out).view(np.int32), np.asarray(exp).view(np.int32)
+    )
