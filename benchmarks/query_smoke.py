@@ -101,8 +101,7 @@ def check_query_equivalence() -> list[str]:
 def check_pushdown_equivalence() -> list[str]:
     """All pushdown plans must report the same qualifying-row count."""
     from repro.engine import datagen, ops
-    from repro.kernels import ops as kops
-    from repro.tasks.pushdown import _pred_bounds, kernel_scan_columns
+    from repro.tasks.pushdown import _pred_bounds, aggregate_at_data
 
     key = jax.random.PRNGKey(7)
     failures = []
@@ -118,7 +117,7 @@ def check_pushdown_equivalence() -> list[str]:
             baseline = int(ops.masked_count(mask))
             _, cnt_j = ops.compact(scanned, mask, cap)
             _, cnt_k = ops.compact(scanned, mask, cap, use_pallas=True)
-            cnt_f = int(kops.filter_agg(kernel_scan_columns(table), lo, hi, -1.0, 1.0)[1])
+            cnt_f = int(aggregate_at_data(table, lo, hi)[1])
             counts = {"baseline": baseline, "pushdown": int(cnt_j),
                       "pushdown+kernel": int(cnt_k), "pushdown_kernel": cnt_f}
             if len(set(counts.values())) != 1:

@@ -15,8 +15,7 @@ user calls:
   requests share one ``group_filter_agg_multi`` pass;
 * pushdown compaction (``engine.ops.compact(..., use_pallas=True)``) at
   selectivity 0.01 (the VMEM-resident kernel) and 0.5 (the HBM-streaming
-  kernel, chunked) against ``kernels.ref.block_compact_ref``;
-* the pushdown plan's ``filter_agg`` kernel against ``kernels.ref``.
+  kernel, chunked) against ``kernels.ref.block_compact_ref``.
 
 Query results are checked against a float64 numpy evaluation of the same
 query on a host copy of the tables (:func:`_reference`): counts and
@@ -288,16 +287,6 @@ def _one_chip(seed: int) -> list[str]:
 
     for sel in (0.01, 0.5):
         _phase(f"compact_sel{sel}", lambda sel=sel: compact(sel), failed)
-
-    def filter_agg():
-        colmat = pushdown.kernel_scan_columns(li)
-        lo, hi = pushdown._pred_bounds(0.1)
-        got, c_s, r_s = _timed(jax.jit(lambda c: kops.filter_agg(c, lo, hi, -1.0, 1.0)), colmat)
-        want = _block(ref.filter_agg_ref(colmat, lo, hi, -1.0, 1.0))
-        dev = _deviation({"sum": got[0], "count": got[1]}, {"sum": want[0], "count": want[1]})
-        return {"count": int(got[1]), "compile_s": c_s, "run_s": r_s, "max_rel_dev": dev}
-
-    _phase("filter_agg", filter_agg, failed)
     return failed
 
 

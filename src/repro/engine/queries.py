@@ -1,26 +1,27 @@
 """TPC-H-pattern queries over the mini engine (the paper's DBMS workload).
 
 Q1  — scan-heavy group-by aggregate over lineitem;
-Q6  — the predicate-pushdown filter+aggregate (also the Pallas filter_agg
-      kernel's workload);
+Q6  — the predicate-pushdown filter+aggregate;
 Q12 — join lineitem x orders + grouped conditional counts.
 
-Each query is a jit-able Table -> dict[str, Array] function; benchmarks
-compare host-style execution vs pushdown-style (see tasks/pushdown.py) and
-Pallas-accelerated variants.
+Each query is a jit-able Table -> dict[str, Array] function (QUERIES), the
+plain jnp plan that the fused plans are tested against.
 
-The ``*_fused`` variants (FUSED_QUERIES) express the same queries as ONE
-``group_filter_agg`` kernel pass each: the predicate program evaluates the
-WHERE clause in registers, derived columns (Q1's disc_price/charge) are
-term products computed in-flight, and the grouped sums/counts accumulate in
-a VMEM tile — instead of the unfused jnp graph's one-HBM-pass-per-aggregate
-``segment_sum`` plan.  Counts and integer-valued aggregates match the
-unfused results exactly; float sums agree to accumulation-order tolerance.
+:data:`SPECS` holds each query's fused plan, once: a column layout, a
+``group_filter_agg`` program, a group count and a demux; the fused queries
+and the serving plans are both derived from it.  ``FUSED_QUERIES``
+(``q1_fused`` ...) runs a query as ONE kernel pass under ``jit``: the
+predicate program evaluates the WHERE clause in registers, derived columns
+(Q1's disc_price/charge) are term products computed in-flight, and the
+grouped sums/counts accumulate in a VMEM tile — instead of the unfused jnp
+graph's one-HBM-pass-per-aggregate ``segment_sum`` plan.  Counts and
+integer-valued aggregates match the unfused results exactly; float sums
+agree to accumulation-order tolerance.
 
-The serving plans (``make_serving_plans``, ``fused_query_serial``/
-``fused_query_batch``) run the same kernel with constants that arrive per
-request: a call copies its constant tables to the device once and the
-kernel's output back once, and returns host numpy values.
+The serving plans (``make_serving_plans``, ``fused_query_batch``) run the
+same kernel with constants that arrive per request: a call copies its
+constant tables to the device once and the kernel's output back once, and
+returns host numpy values.
 """
 from __future__ import annotations
 
@@ -83,22 +84,6 @@ def q6(lineitem: Table, year: int = 1994, discount: float = 0.06, qty: float = 2
     return {"revenue": revenue, "rows": ops.masked_count(mask)}
 
 
-def q6_columns(lineitem: Table, year: int = 1994, discount: float = 0.06, qty: float = 24.0):
-    """Q6 reshaped for the fused Pallas filter_agg kernel: a [4, N] column
-    block + bounds. quantity < qty folds into a between(0, qty) bound by
-    packing quantity as filter-col-1; the discount band becomes the c0 bound
-    after swapping roles (two range predicates exactly fit the kernel; the
-    third is pre-masked into the value column — documented junk-free)."""
-    lo = datagen.date(year)
-    hi = datagen.date(year + 1)
-    qmask = lineitem["l_quantity"] < qty
-    value = jnp.where(qmask, lineitem["l_extendedprice"], 0.0)
-    cols = jnp.stack(
-        [lineitem["l_shipdate"], lineitem["l_discount"], value, lineitem["l_discount"]]
-    )
-    return cols, (lo, hi, discount - 0.011, discount + 0.011)
-
-
 # Q12's shipmode IN-list, resolved against the dictionary order once so the
 # fused and unfused plans can't drift apart.
 Q12_SHIPMODES = tuple(datagen.SHIPMODE.index(m) for m in ("MAIL", "SHIP"))
@@ -128,10 +113,10 @@ def q12(lineitem: Table, orders: Table, year: int = 1994):
 
 
 # ---------------------------------------------------------------------------
-# Fused variants: each query as one group_filter_agg pass.  The kernel
-# programs are built by per-query ``*_program`` functions so that constants
-# can also be stacked into *batch inputs* for the scan-sharing serving path
-# (``fused_query_batch``) instead of being baked at trace time.
+# Fused plans: each query as one group_filter_agg pass.  A query's
+# ``*_program`` builds its kernel program from its constants, so that the
+# serving path can stack the constants of many requests into batch inputs
+# (``fused_query_batch``) instead of baking them in at trace time.
 def q1_program(delta_days: float = 90.0):
     """Q1's kernel program: (pred_ops, pred_consts, agg_ops, agg_consts)."""
     cutoff = datagen.date(1998, 12, 1) - delta_days
@@ -182,22 +167,12 @@ def _q1_demux(out):
     return agg
 
 
-def q1_fused(
-    lineitem: Table, delta_days: float = 90.0, use_pallas: bool = True
-) -> dict[str, jax.Array]:
-    """Q1 as a single kernel pass: 6 groups x 5 aggregates + count, with
-    disc_price/charge evaluated in-register by the term program."""
-    cols, keys = _q1_layout(lineitem)
-    pred_ops, pred_consts, agg_ops, agg_consts = q1_program(delta_days)
-    out = kops.group_filter_agg(
-        cols, keys, pred_ops, pred_consts, agg_ops, agg_consts,
-        num_groups=6, use_pallas=use_pallas,
-    )
-    return _q1_demux(out)
-
-
 def q6_program(year: int = 1994, discount: float = 0.06, qty: float = 24.0):
-    """Q6's kernel program: three range predicates + one product-sum."""
+    """Q6's kernel program: three range predicates + one product-sum.
+
+    All three predicates run in the kernel, so the row count matches
+    ``q6`` exactly.
+    """
     lo = datagen.date(year)
     hi = datagen.date(year + 1)
     pred = encode_predicates(
@@ -226,29 +201,6 @@ def _q6_layout(lineitem: Table) -> tuple[jax.Array, jax.Array]:
 
 def _q6_demux(out):
     return {"revenue": out[0, 0], "rows": out[0, 1].astype(np.int32)}
-
-
-def q6_fused(
-    lineitem: Table,
-    year: int = 1994,
-    discount: float = 0.06,
-    qty: float = 24.0,
-    use_pallas: bool = True,
-):
-    """Q6 as a 1-group program: three range predicates + one product-sum.
-
-    Unlike ``q6_columns`` (which pre-masks the quantity predicate into the
-    value column to fit ``filter_agg``'s fixed two-predicate shape), the
-    general kernel expresses all three predicates, so the returned row
-    count matches ``q6`` exactly too.
-    """
-    cols, keys = _q6_layout(lineitem)
-    pred_ops, pred_consts, agg_ops, agg_consts = q6_program(year, discount, qty)
-    out = kops.group_filter_agg(
-        cols, keys, pred_ops, pred_consts, agg_ops, agg_consts,
-        num_groups=1, use_pallas=use_pallas,
-    )
-    return _q6_demux(out)
 
 
 def q12_program(year: int = 1994):
@@ -293,6 +245,9 @@ _Q12_SELECT = np.isin(np.arange(len(datagen.SHIPMODE)), Q12_SHIPMODES).astype(np
 
 
 def _q12_demux(out):
+    """Q12's ``shipmode IN (MAIL, SHIP)``: the kernel groups every shipmode
+    (rows of other shipmodes land in other groups), and the demux keeps the
+    two selected groups, so the counts stay integer-exact."""
     return {
         "high_line_count": out[:, 0] * _Q12_SELECT,
         "low_line_count": out[:, 1] * _Q12_SELECT,
@@ -300,27 +255,52 @@ def _q12_demux(out):
     }
 
 
-def q12_fused(
-    lineitem: Table, orders: Table, year: int = 1994, use_pallas: bool = True
-):
-    """Q12 as join-gather + one kernel pass over all 7 shipmode groups.
+@dataclasses.dataclass(frozen=True)
+class QuerySpec:
+    """One query's fused plan; :data:`SPECS` is the only place it is
+    written down.
 
-    The ``shipmode IN (MAIL, SHIP)`` membership predicate is equivalent to
-    selecting those groups of the full grouped result (rows of other
-    shipmodes land in other groups), so it becomes a post-kernel group mask
-    instead of a row predicate — counts stay integer-exact.
+    ``layout(*tables)`` lays the tables out as the kernel's ``[C, N]``
+    columns and ``[N]`` group keys, whatever the constants; the tables are
+    ``(lineitem, orders)`` if ``needs_orders``, else ``(lineitem,)``.
+    ``program(**constants)`` builds ``(pred_ops, pred_consts, agg_ops,
+    agg_consts)`` in numpy.  ``demux(out)`` turns one ``[G, A + 1]`` kernel
+    output slot, a device or a host array, into the query's result dict.
     """
-    cols, keys = _q12_layout(lineitem, orders)
-    pred_ops, pred_consts, agg_ops, agg_consts = q12_program(year)
-    out = kops.group_filter_agg(
-        cols, keys, pred_ops, pred_consts, agg_ops, agg_consts,
-        num_groups=len(datagen.SHIPMODE), use_pallas=use_pallas,
-    )
-    return _q12_demux(out)
+
+    layout: Callable[..., tuple[jax.Array, jax.Array]]
+    program: Callable[..., tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]]
+    num_groups: int
+    demux: Callable[[Any], dict[str, Any]]
+    needs_orders: bool = False
+
+
+SPECS: dict[str, QuerySpec] = {
+    "q1": QuerySpec(_q1_layout, q1_program, 6, _q1_demux),
+    "q6": QuerySpec(_q6_layout, q6_program, 1, _q6_demux),
+    "q12": QuerySpec(_q12_layout, q12_program, len(datagen.SHIPMODE), _q12_demux, needs_orders=True),
+}
+
+
+def _fused(name: str, spec: QuerySpec) -> Callable[..., dict[str, jax.Array]]:
+    def fused(*tables: Table, use_pallas: bool = True, **constants) -> dict[str, jax.Array]:
+        """The query as one ``group_filter_agg`` pass over ``tables``, its
+        constants given as keywords of the spec's ``program``."""
+        cols, keys = spec.layout(*tables)
+        pred_ops, pred_consts, agg_ops, agg_consts = spec.program(**constants)
+        out = kops.group_filter_agg_multi(
+            cols, keys, pred_ops, pred_consts[None], agg_ops, agg_consts[None],
+            num_groups=spec.num_groups, use_pallas=use_pallas,
+        )
+        return spec.demux(out[0])
+
+    fused.__name__ = fused.__qualname__ = f"{name}_fused"
+    return fused
 
 
 QUERIES = {"q1": q1, "q6": q6, "q12": q12}
-FUSED_QUERIES = {"q1": q1_fused, "q6": q6_fused, "q12": q12_fused}
+FUSED_QUERIES = {name: _fused(name, spec) for name, spec in SPECS.items()}
+q1_fused, q6_fused, q12_fused = (FUSED_QUERIES[q] for q in ("q1", "q6", "q12"))
 #: Result keys that are row counts: every plan must agree on them exactly,
 #: while float sums may differ in the order of their additions.
 COUNT_KEYS = ("count", "rows", "high_line_count", "low_line_count")
@@ -362,30 +342,26 @@ def _plan_program(program_fn) -> Callable[[dict[str, Any]], tuple[np.ndarray, np
 def make_serving_plans(
     lineitem: Table, orders: Table | None = None
 ) -> dict[str, ServingPlan]:
-    """Serving plans for every fused query servable over these tables.
+    """Serving plans for every query of :data:`SPECS` servable over these
+    tables.
 
     Q12 needs ``orders`` for its join; without it only Q1/Q6 are planned.
     """
     plans: dict[str, ServingPlan] = {}
-    specs: list[tuple[str, tuple[jax.Array, jax.Array], Any, int, Any]] = [
-        ("q1", _q1_layout(lineitem), q1_program, 6, _q1_demux),
-        ("q6", _q6_layout(lineitem), q6_program, 1, _q6_demux),
-    ]
-    if orders is not None:
-        specs.append(
-            ("q12", _q12_layout(lineitem, orders), q12_program, len(datagen.SHIPMODE), _q12_demux)
-        )
-    for name, (cols, keys), program_fn, num_groups, demux in specs:
-        pred_ops, _, agg_ops, _ = program_fn()
+    for name, spec in SPECS.items():
+        if spec.needs_orders and orders is None:
+            continue
+        cols, keys = spec.layout(*((lineitem, orders) if spec.needs_orders else (lineitem,)))
+        pred_ops, _, agg_ops, _ = spec.program()
         plans[name] = ServingPlan(
             name=name,
             cols=cols,
             keys=keys,
             pred_ops=jnp.asarray(pred_ops),
             agg_ops=jnp.asarray(agg_ops),
-            num_groups=num_groups,
-            program=_plan_program(program_fn),
-            demux=demux,
+            num_groups=spec.num_groups,
+            program=_plan_program(spec.program),
+            demux=spec.demux,
         )
     return plans
 
@@ -393,24 +369,8 @@ def make_serving_plans(
 def fused_query_serial(
     plan: ServingPlan, params: dict[str, Any], *, use_pallas: bool = True
 ) -> dict[str, np.ndarray]:
-    """One request through the single-program kernel — the serving oracle.
-
-    The constant tables go to the device in one explicit copy and the
-    kernel's output comes back in another, inside ``serve.wait``, the only
-    place the host waits for the device; the result holds host numpy
-    values.
-    """
-    with tracing.span("serve.consts"):
-        pred_consts, agg_consts = jax.device_put(plan.program(params))
-    with tracing.span("serve.launch"):
-        out = kops.group_filter_agg(
-            plan.cols, plan.keys, plan.pred_ops, pred_consts, plan.agg_ops, agg_consts,
-            num_groups=plan.num_groups, use_pallas=use_pallas,
-        )
-    with tracing.span("serve.wait"):
-        out = jax.device_get(out)
-    with tracing.span("serve.demux"):
-        return plan.demux(out)
+    """One request: the one-slot batch of :func:`fused_query_batch`."""
+    return fused_query_batch(plan, [params], use_pallas=use_pallas)[0]
 
 
 def fused_query_batch(
@@ -424,11 +384,11 @@ def fused_query_batch(
     Each request's constants become one slot of the batched SMEM program
     tables, stacked on the host and copied to the device at once; the
     ``[B, G, A + 1]`` output comes back in one copy and demultiplexes per
-    request in numpy.  Results are host numpy values and equal
-    ``fused_query_serial`` on the same constants: counts exactly, float
-    sums within a few ulps (the per-program block order is the
-    single-program path's; the additions inside one block's dot may be
-    ordered differently).
+    request in numpy.  Results are host numpy values.  A slot equals a
+    one-slot batch of the same constants: counts exactly, float sums within
+    a few ulps (every slot visits the data blocks in the same order; the
+    additions inside one block's dot may be ordered differently for
+    another batch size).
     """
     with tracing.span("serve.consts"):
         consts = [plan.program(p) for p in param_list]

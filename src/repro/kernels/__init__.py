@@ -2,8 +2,8 @@
 pure-jnp oracles.
 
 Public API lives in repro.kernels.ops: flash_attention, decode_attention,
-ssd_intra, gmm, filter_agg, group_filter_agg(_multi), block_compact — each
-with a use_pallas=False oracle path.
+ssd_intra, gmm, group_filter_agg_multi, block_compact — each with a
+use_pallas=False oracle path.
 """
 from repro.kernels import ops, ref
 

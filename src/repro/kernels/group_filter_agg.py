@@ -1,12 +1,12 @@
 """Single-pass fused grouped filter+aggregate kernel (the DBMS hot loop).
 
-``filter_scan.filter_agg`` fuses exactly one query shape (TPC-H Q6: two
-range predicates, one product-sum).  The DBMS workloads (paper §3.6,
-Fig. 15) need the general form: Q1 is a 6-group × 5-aggregate scan with two
-derived columns, Q12 is grouped conditional counts behind four predicates —
-both executed today as unfused jnp graphs that stream every column through
-HBM several times (mask pass, derived-column passes, then one
-``segment_sum`` pass per aggregate).
+The DBMS workloads (paper §3.6, Fig. 15) and the pushdown plans filter and
+aggregate in many shapes: Q6 and the pushdown aggregate are one group
+behind range predicates, Q1 is a 6-group × 5-aggregate scan with two
+derived columns, Q12 is grouped conditional counts behind four predicates.
+Their unfused jnp graphs stream every column through HBM several times
+(mask pass, derived-column passes, then one ``segment_sum`` pass per
+aggregate).
 
 This kernel makes any such query ONE pass over a ``[C, N]`` column block:
 

@@ -24,7 +24,6 @@ from repro.kernels.block_compact import (
     stream_init as _stream_init,
 )
 from repro.kernels.decode_attention import decode_attention as _decode_kernel
-from repro.kernels.filter_scan import filter_agg as _filter_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.group_filter_agg import group_filter_agg as _group_kernel
 from repro.kernels.moe_gmm import gmm as _gmm_kernel
@@ -122,20 +121,6 @@ def gmm(lhs, rhs, *, block_c: int = 256, block_f: int = 256, block_d: int = 512,
     return out[:, :c, :f]
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "use_pallas"))
-def filter_agg(cols, lo, hi, lo2, hi2, *, block_n: int = 16384, use_pallas: bool = True):
-    """Fused filter+aggregate; returns [2] (sum, count)."""
-    if not use_pallas:
-        return ref.filter_agg_ref(cols, lo, hi, lo2, hi2)
-    cols_p, n0 = _pad_to(cols, 1, min(block_n, cols.shape[1]))
-    if cols_p.shape != cols.shape:
-        # padded rows must fail the predicate: fill filter cols with +inf
-        pad = cols_p.shape[1] - cols.shape[1]
-        filler = jnp.full((cols.shape[0], pad), jnp.finfo(jnp.float32).max, cols.dtype)
-        cols_p = jnp.concatenate([cols, filler], axis=1)
-    return _filter_kernel(cols_p, lo, hi, lo2, hi2, block_n=block_n, interpret=interpret_mode())
-
-
 def _pad_group_rows(cols, keys, block_n: int):
     """Pad the row axis to a whole number of blocks.  Padded rows carry key
     -1: they match no group whatever the predicate program evaluates to on
@@ -153,47 +138,25 @@ def _pad_group_rows(cols, keys, block_n: int):
 @functools.partial(
     jax.jit, static_argnames=("num_groups", "block_n", "use_pallas")
 )
-def group_filter_agg(
-    cols, keys, pred_ops, pred_consts, agg_ops, agg_consts, *,
-    num_groups: int, block_n: int = 16384, use_pallas: bool = True,
-):
-    """Single-pass grouped filter+aggregate over a [C, N] column block.
-
-    ``pred_ops``/``pred_consts``/``agg_ops``/``agg_consts`` encode the
-    predicate and aggregate programs (see kernels/group_filter_agg.py —
-    ``encode_predicates`` / ``encode_aggregates`` build them).  Returns
-    [num_groups, A + 1]: per-group aggregate sums, then the masked count.
-    This is the one-program batch of :func:`group_filter_agg_multi`.
-    """
-    if not use_pallas:
-        return ref.group_filter_agg_ref(
-            cols, keys, pred_ops, pred_consts, agg_ops, agg_consts, num_groups
-        )
-    cols, keys, bn = _pad_group_rows(cols, keys, block_n)
-    return _group_kernel(
-        cols, keys, pred_ops, pred_consts[None], agg_ops, agg_consts[None],
-        num_groups=num_groups, block_n=bn, interpret=interpret_mode(),
-    )[0]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_groups", "block_n", "use_pallas")
-)
 def group_filter_agg_multi(
     cols, keys, pred_ops, pred_consts, agg_ops, agg_consts, *,
     num_groups: int, block_n: int = 16384, use_pallas: bool = True,
 ):
-    """Scan-shared batch of ``group_filter_agg``: B constant sets, one pass.
+    """Single-pass grouped filter+aggregate over a [C, N] column block, for
+    B constant sets of one program in one pass.
 
+    ``pred_ops``/``pred_consts``/``agg_ops``/``agg_consts`` encode the
+    predicate and aggregate programs (see kernels/group_filter_agg.py —
+    ``encode_predicates`` / ``encode_aggregates`` build them).
     ``pred_consts``/``agg_consts`` carry a leading program dimension
     (``[B, K, 2]`` / ``[B, A, MAX_TERMS]``) and are *traced inputs*, not
     trace-time constants — one compiled executable serves any predicate
-    bounds of the same query shape.  Returns ``[B, num_groups, A + 1]``.
-    The single-program call runs the same kernel with B = 1, and each slot
-    visits the data blocks in the same order, so slot ``b`` matches the
-    single-program call with that program's constants: counts exactly,
-    float sums to within the order of the additions inside one block's dot,
-    which the compiler may choose differently for different B.
+    bounds of the same query shape; a single query is B = 1.  Returns
+    ``[B, num_groups, A + 1]``: per-group aggregate sums, then the masked
+    count.  Each slot visits the data blocks in the same order, so slot
+    ``b`` matches a B = 1 call with that program's constants: counts
+    exactly, float sums to within the order of the additions inside one
+    block's dot, which the compiler may choose differently for different B.
     """
     if not use_pallas:
         return ref.group_filter_agg_multi_ref(

@@ -181,21 +181,3 @@ def block_compact_ref(
     out = jnp.take(cols, safe, axis=1)
     out = jnp.where(in_range[None, :], out, 0.0)
     return out, jnp.sum(mask.astype(jnp.int32))
-
-
-# ---------------------------------------------------------------------------
-def filter_agg_ref(
-    cols: jax.Array,  # [4, N] f32: (key, lo-col, hi-col, value) layout per op
-    lo: jax.Array,  # scalar predicate bounds on cols[0]
-    hi: jax.Array,
-    lo2: jax.Array,  # bounds on cols[1]
-    hi2: jax.Array,
-) -> jax.Array:
-    """Fused scan+filter+aggregate (TPC-H Q6 pattern):
-    sum(cols[2] * cols[3]) where lo <= cols[0] < hi and lo2 <= cols[1] < hi2.
-    Returns [2]: (sum, count)."""
-    c0, c1, c2, c3 = cols
-    mask = (c0 >= lo) & (c0 < hi) & (c1 >= lo2) & (c1 < hi2)
-    s = jnp.sum(jnp.where(mask, c2.astype(jnp.float32) * c3.astype(jnp.float32), 0.0))
-    n = jnp.sum(mask.astype(jnp.float32))
-    return jnp.stack([s, n])

@@ -69,10 +69,10 @@ class QueryServer:
     """Long-lived serving loop over a set of compiled query plans.
 
     ``max_batch`` bounds the scan-sharing width; 1 serves strictly one
-    request per kernel pass (the serial baseline).  Batch sizes > 1 are
-    padded up to the next power of two (padding slots repeat the first
-    request's constants and are discarded at demux) so the number of
-    compiled executables stays logarithmic in ``max_batch``.
+    request per kernel pass (the serial baseline).  Every batch is padded
+    up to the next power of two (padding slots repeat the first request's
+    constants and are discarded at demux) so the number of compiled
+    executables stays logarithmic in ``max_batch``.
 
     Each ``step`` is a tick of :data:`repro.core.tracing.RECORDER`: its
     phases are ``serve.*`` spans, and its record counts the requests, the
@@ -113,8 +113,7 @@ class QueryServer:
         for name in queries or list(self.plans):
             plan = self.plans[name]
             params = sample_params(name, random.Random(0))
-            queries_mod.fused_query_serial(plan, params, use_pallas=self.use_pallas)
-            size = 2
+            size = 1
             while size <= self.max_batch:
                 queries_mod.fused_query_batch(plan, [params] * size, use_pallas=self.use_pallas)
                 size *= 2
@@ -126,9 +125,6 @@ class QueryServer:
         plan = self.plans[batch[0].query]
         self.kernel_calls += 1
         tick = tracing.RECORDER.current()
-        if len(batch) == 1:
-            tick.slots = 1
-            return [queries_mod.fused_query_serial(plan, batch[0].params, use_pallas=self.use_pallas)]
         tick.slots = _pow2_at_least(len(batch))
         padded = [r.params for r in batch]
         padded += [batch[0].params] * (tick.slots - len(batch))

@@ -24,6 +24,7 @@ from repro.core.registry import register
 from repro.core.task import Task, TaskContext
 from repro.core.timing import measure
 from repro.kernels import ops as kops
+from repro.kernels.group_filter_agg import encode_aggregates, encode_predicates
 
 _SIZES = {"small": 128, "medium": 512, "large": 2048}
 
@@ -59,11 +60,15 @@ class PallasAccelTask(Task):
             fn = lambda: kops.gmm(lhs, rhs, block_c=128, block_f=128, block_d=128,
                                   use_pallas=use_pallas)
             flops = 2.0 * e * c * d * f
-        else:  # filter_agg
+        else:  # filter_agg: two range predicates, one product-sum, one group
             n = s * 1024
             cols = jax.random.uniform(key, (4, n), jnp.float32)
-            fn = lambda: kops.filter_agg(cols, 0.2, 0.8, 0.1, 0.9, block_n=16384,
-                                         use_pallas=use_pallas)
+            keys = jnp.zeros((n,), jnp.int32)
+            pred_ops, pred_consts = encode_predicates([("range", 0, 0.2, 0.8), ("range", 1, 0.1, 0.9)])
+            agg_ops, agg_consts = encode_aggregates([[("col", 2), ("col", 3)]])
+            fn = lambda: kops.group_filter_agg_multi(
+                cols, keys, pred_ops, pred_consts[None], agg_ops, agg_consts[None],
+                num_groups=1, block_n=16384, use_pallas=use_pallas)
             flops = 6.0 * n  # 4 compares + mul + add
 
         times = measure(fn, iters=ctx.iters, warmup=ctx.warmup)

@@ -15,8 +15,9 @@ Three plans for ``SELECT ... WHERE lo <= l_shipdate < hi`` over lineitem:
              Pallas kernel (HBM-streaming past its VMEM budget),
              ``impl=jnp`` with ``nonzero`` + gather.  On one device the
              consumer owns the whole table and nothing crosses chips.
-  pushdown_kernel — filter and aggregate at the data in one pass (the Q6
-             ``filter_agg`` kernel): only the aggregate travels.
+  pushdown_kernel — filter and aggregate at the data in one
+             ``group_filter_agg`` pass (:func:`aggregate_at_data`): only
+             the (sum, count) pair travels.
 
 ``impl`` is ignored by the other plans.  Params: scale x selectivity.
 """
@@ -33,6 +34,8 @@ from repro.core.registry import register
 from repro.core.task import Task, TaskContext
 from repro.core.timing import measure
 from repro.engine import datagen, ops
+from repro.kernels import ops as kops
+from repro.kernels.group_filter_agg import encode_aggregates, encode_predicates
 from repro.launch.mesh import mesh_1d
 
 _SCALES = {"0.01": 60_000, "0.1": 600_000, "1.0": 6_000_000}
@@ -45,16 +48,19 @@ def _pred_bounds(selectivity: float) -> tuple[float, float]:
     return float(lo), float(lo + width)
 
 
-def kernel_scan_columns(table) -> jax.Array:
-    """[4, N] column matrix for the fused filter_agg plan: shipdate and
-    discount as the two filter columns, extendedprice x 1.0 as the value
-    product.  The single source for the plan's column layout — the CI smoke
-    and tests reuse it so they validate the exact plan the task measures."""
-    n = table.num_rows
-    return jnp.stack(
-        [table["l_shipdate"], table["l_discount"],
-         table["l_extendedprice"], jnp.ones((n,), jnp.float32)]
+def aggregate_at_data(table, lo: float, hi: float) -> tuple[jax.Array, jax.Array]:
+    """The ``pushdown_kernel`` plan: (SUM(l_extendedprice), COUNT(*)) over
+    the rows with ``lo <= l_shipdate < hi``, as one G = 1
+    ``group_filter_agg`` program over those two columns.  The CI smoke and
+    the tests call it, so they check the plan the task measures."""
+    cols = jnp.stack([table["l_shipdate"], table["l_extendedprice"]])
+    keys = jnp.zeros((table.num_rows,), jnp.int32)
+    pred_ops, pred_consts = encode_predicates([("range", 0, lo, hi)])
+    agg_ops, agg_consts = encode_aggregates([[("col", 1)]])
+    out = kops.group_filter_agg_multi(
+        cols, keys, pred_ops, pred_consts[None], agg_ops, agg_consts[None], num_groups=1
     )
+    return out[0, 0, 0], out[0, 0, 1]
 
 
 @register
@@ -116,14 +122,8 @@ class PushdownTask(Task):
             moved_bytes = owners * owner_cap * 16  # 4 cols x 4 B per provisioned slot
             moved_bytes_exact = sum(min(int(c), owner_cap) for c in jax.device_get(counts)) * 16
         else:  # pushdown_kernel: fused Pallas filter+aggregate, zero row movement
-            from repro.kernels import ops as kops
-
-            colmat = kernel_scan_columns(table)
-
-            def fn(c):
-                return kops.filter_agg(c, lo, hi, -1.0, 1.0)
-
-            times = measure(fn, colmat, iters=ctx.iters, warmup=ctx.warmup)
+            fn = jax.jit(lambda t: aggregate_at_data(t, lo, hi))
+            times = measure(fn, scanned, iters=ctx.iters, warmup=ctx.warmup)
             moved_bytes = 8  # one (sum, count) pair
             moved_bytes_exact = moved_bytes
 

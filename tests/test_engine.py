@@ -105,12 +105,14 @@ def test_q6_matches_numpy(li):
 
 
 def test_q6_kernel_equals_engine(li):
-    from repro.kernels import ops as kops
+    """The pushdown task's kernel plan against the engine's masked ops."""
+    from repro.tasks.pushdown import aggregate_at_data
 
-    res = jax.jit(queries.q6)(li)
-    cols, bounds = queries.q6_columns(li)
-    out = kops.filter_agg(cols, *bounds, block_n=8192)
-    np.testing.assert_allclose(float(out[0]), float(res["revenue"]), rtol=1e-5)
+    lo, hi = datagen.date(1994), datagen.date(1995)
+    total, count = aggregate_at_data(li, lo, hi)
+    mask = ops.pred_between(li["l_shipdate"], lo, hi)
+    assert int(count) == int(ops.masked_count(mask)) > 0
+    np.testing.assert_allclose(float(total), float(ops.masked_sum(li["l_extendedprice"], mask)), rtol=1e-5)
 
 
 def test_q12_runs_and_counts_bounded(li, od):

@@ -179,15 +179,22 @@ def test_gmm_sweep(dtype, e, c, d, f, bc, bf, bd):
 )
 @settings(max_examples=10, deadline=None)
 def test_filter_agg_property(n, lo, width):
-    """Kernel == oracle == plain numpy for random predicates (incl. padding)."""
+    """The one-group, two-range, one-product program (the ``filter_agg``
+    workload): kernel == oracle == plain numpy, padding included."""
     cols = jax.random.uniform(jax.random.fold_in(KEY, n), (4, n), jnp.float32)
+    keys = jnp.zeros((n,), jnp.int32)
     hi = lo + width
-    out = ops.filter_agg(cols, lo, hi, 0.2, 0.9, block_n=4096)
-    exp = ref.filter_agg_ref(cols, lo, hi, 0.2, 0.9)
+    po, pc = encode_predicates([("range", 0, lo, hi), ("range", 1, 0.2, 0.9)])
+    ao, ac = encode_aggregates([[("col", 2), ("col", 3)]])
+    out = ops.group_filter_agg_multi(cols, keys, po, pc[None], ao, ac[None],
+                                     num_groups=1, block_n=4096)[0]
+    exp = ref.group_filter_agg_ref(cols, keys, po, pc, ao, ac, 1)
+    assert out.shape == (1, 2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-4, atol=1e-4)
-    c = np.asarray(cols)
+    c = np.asarray(cols, np.float64)
     mask = (c[0] >= lo) & (c[0] < hi) & (c[1] >= 0.2) & (c[1] < 0.9)
-    assert int(out[1]) == int(mask.sum())
+    assert int(out[0, 1]) == int(mask.sum())
+    np.testing.assert_allclose(float(out[0, 0]), (c[2] * c[3])[mask].sum(), rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -220,8 +227,8 @@ def _gfa_case(n, num_groups, lo, width, seed):
 def test_group_filter_agg_property(n, num_groups, lo, width):
     """Kernel == oracle == numpy across group counts, predicates, padding."""
     cols, keys, po, pc, ao, ac = _gfa_case(n, num_groups, lo, width, n + num_groups)
-    out = ops.group_filter_agg(cols, keys, po, pc, ao, ac,
-                               num_groups=num_groups, block_n=4096)
+    out = ops.group_filter_agg_multi(cols, keys, po, pc[None], ao, ac[None],
+                                     num_groups=num_groups, block_n=4096)[0]
     exp = ref.group_filter_agg_ref(cols, keys, po, pc, ao, ac, num_groups)
     assert out.shape == (num_groups, 6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-5, atol=1e-3)
@@ -242,7 +249,8 @@ def test_group_filter_agg_degenerate_masks(all_pass):
     preds = [("range", 0, None, None)] if all_pass else [("range", 0, 0.5, 0.5)]
     po, pc = encode_predicates(preds)
     ao, ac = encode_aggregates([[("col", 1)], [("col", 1), ("col", 2)]])
-    out = ops.group_filter_agg(cols, keys, po, pc, ao, ac, num_groups=6, block_n=4096)
+    out = ops.group_filter_agg_multi(cols, keys, po, pc[None], ao, ac[None],
+                                     num_groups=6, block_n=4096)[0]
     exp = ref.group_filter_agg_ref(cols, keys, po, pc, ao, ac, 6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=2e-5, atol=1e-4)
     assert int(np.asarray(out[:, -1]).sum()) == (n if all_pass else 0)
@@ -251,7 +259,8 @@ def test_group_filter_agg_degenerate_masks(all_pass):
 def test_group_filter_agg_ref_escape_hatch():
     """use_pallas=False routes to the oracle (modulo jit) — same values."""
     cols, keys, po, pc, ao, ac = _gfa_case(4096, 6, 0.1, 0.6, 77)
-    a = ops.group_filter_agg(cols, keys, po, pc, ao, ac, num_groups=6, use_pallas=False)
+    a = ops.group_filter_agg_multi(cols, keys, po, pc[None], ao, ac[None],
+                                   num_groups=6, use_pallas=False)[0]
     b = ref.group_filter_agg_ref(cols, keys, po, pc, ao, ac, 6)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 

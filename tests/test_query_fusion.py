@@ -51,8 +51,7 @@ def test_q1_fused_zero_delta_includes_all_rows(li):
 def test_q6_fused_equals_q6(li):
     ref = jax.jit(queries.q6)(li)
     fused = jax.jit(queries.q6_fused)(li)
-    # unlike q6_columns+filter_agg, the general program expresses ALL THREE
-    # predicates, so the row count matches exactly too
+    # the program expresses all three predicates: the row count is exact
     assert int(ref["rows"]) == int(fused["rows"])
     np.testing.assert_allclose(float(ref["revenue"]), float(fused["revenue"]), rtol=2e-5)
 
@@ -111,13 +110,7 @@ def test_pushdown_plans_agree_at_every_param_point():
     """baseline / pushdown(jnp) / pushdown(kernel) / pushdown_kernel report
     the same qualifying-row count (and consistent sums) at every
     (scale, selectivity) point of the task's param_space."""
-    from repro.kernels import ops as kops
-    from repro.tasks.pushdown import (
-        _SCALES,
-        PushdownTask,
-        _pred_bounds,
-        kernel_scan_columns,
-    )
+    from repro.tasks.pushdown import _SCALES, PushdownTask, _pred_bounds, aggregate_at_data
 
     task = PushdownTask()
     sels = task.param_space["selectivity"]
@@ -144,9 +137,9 @@ def test_pushdown_plans_agree_at_every_param_point():
 
             # the fully-fused plan's count agrees too (its aggregate matches
             # to accumulation-order tolerance)
-            agg = kops.filter_agg(kernel_scan_columns(table), lo, hi, -1.0, 1.0)
-            assert int(agg[1]) == baseline_cnt, (scale, sel)
-            np.testing.assert_allclose(float(agg[0]), baseline_sum, rtol=2e-5)
+            total, count = aggregate_at_data(table, lo, hi)
+            assert int(count) == baseline_cnt, (scale, sel)
+            np.testing.assert_allclose(float(total), baseline_sum, rtol=2e-5)
 
 
 # -- min-time measurement floor ----------------------------------------------

@@ -191,6 +191,20 @@ def _fused(qname, tables, params):
     return queries.FUSED_QUERIES[qname](*args, **params)
 
 
+@pytest.mark.parametrize("qname", sorted(queries.SPECS))
+def test_every_spec_is_fused_and_served_alike(tables, plans, qname):
+    """Each spec gives one fused query and one serving plan, and the two
+    answer alike: counts exactly, float sums within a few ulps."""
+    spec = queries.SPECS[qname]
+    assert set(queries.FUSED_QUERIES) == set(plans) == set(queries.SPECS)
+    assert queries.FUSED_QUERIES[qname].__name__ == f"{qname}_fused"
+    plan = plans[qname]
+    assert plan.num_groups == spec.num_groups and plan.demux is spec.demux
+    params = sample_params(qname, random.Random(17))
+    (got,) = queries.fused_query_batch(plan, [params])
+    _assert_same_result(_fused(qname, tables, params), got, qname)
+
+
 def _is_host_value(x) -> bool:
     return isinstance(x, (np.ndarray, np.generic))
 
@@ -205,9 +219,6 @@ def test_batch_equals_the_fused_device_path(tables, plans, qname, size):
     slots = 1 << (size - 1).bit_length()
     padded = param_list + [param_list[0]] * (slots - size)
     got = queries.fused_query_batch(plans[qname], padded)[:size]
-    if size == 1:
-        got.append(queries.fused_query_serial(plans[qname], param_list[0]))
-        param_list = param_list * 2
     for params, result in zip(param_list, got, strict=True):
         _assert_same_result(_fused(qname, tables, params), result, (qname, params))
         assert all(_is_host_value(v) for v in result.values()), result
@@ -277,6 +288,30 @@ def test_server_batched_results_byte_equal_serial(plans):
         want = queries.fused_query_serial(plans["q6"], req.params)
         _assert_same_result(want, c.result, req.uid)
     assert server.kernel_calls == 1  # one HBM pass for all seven requests
+
+
+def test_a_one_request_tick_is_a_one_slot_batch(plans, monkeypatch):
+    """A lone request takes the batch path, padded to one slot, like any
+    other tick."""
+    from repro.core import tracing
+
+    calls = []
+    batch = queries.fused_query_batch
+
+    def counted(plan, param_list, **kw):
+        calls.append(len(param_list))
+        return batch(plan, param_list, **kw)
+
+    monkeypatch.setattr(queries, "fused_query_batch", counted)
+    monkeypatch.setattr(queries, "fused_query_serial", None)  # no second launch path
+    server = QueryServer(plans, max_batch=8)
+    req = QueryRequest(uid=0, query="q1", params=sample_params("q1", random.Random(3)))
+    server.submit(req)
+    (done,) = server.step()
+    assert calls == [1] and server.kernel_calls == 1
+    assert tracing.RECORDER.ticks[-1].slots == 1
+    assert done.uid == 0 and done.batch_size == 1
+    _assert_same_result(batch(plans["q1"], [req.params])[0], done.result, "q1")
 
 
 def test_server_coalesces_only_same_query_shape(plans):

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import block_compact, filter_scan, group_filter_agg, ops
+from repro.kernels import block_compact, group_filter_agg, ops
 
 ROWS = 6_000_000  # lineitem at SF1
 GROUP_BLOCK = 16384  # kernels.ops default block_n for the scan kernels
@@ -51,11 +51,12 @@ def _assert_kernel_compiles(fn, *args):
 
 
 # (groups, columns, predicates, aggregates, programs) of the fused q6, q1
-# and q12 plans, and the serving batch of eight q12 requests.
+# and q12 plans, the serving batch of eight q12 requests, and the pushdown
+# task's aggregate at the data.
 @pytest.mark.parametrize(
     "groups,cols,preds,aggs,progs",
-    [(1, 4, 3, 1, 1), (6, 5, 1, 5, 1), (7, 4, 3, 2, 1), (7, 4, 3, 2, 8)],
-    ids=["q6", "q1", "q12", "q12-batch8"],
+    [(1, 4, 3, 1, 1), (6, 5, 1, 5, 1), (7, 4, 3, 2, 1), (7, 4, 3, 2, 8), (1, 2, 1, 1, 1)],
+    ids=["q6", "q1", "q12", "q12-batch8", "pushdown"],
 )
 def test_group_filter_agg_compiles(shape, groups, cols, preds, aggs, progs):
     n = _padded(GROUP_BLOCK)
@@ -95,13 +96,6 @@ def test_block_compact_stream_chunk_compiles(shape):
         shape(carry.shape, carry.dtype),
         shape((4, CHUNK), jnp.float32),
         shape((1, CHUNK), jnp.int32),
-    )
-
-
-def test_filter_agg_compiles(shape):
-    _assert_kernel_compiles(
-        lambda c: filter_scan.filter_agg(c, 8035.0, 8287.6, -1.0, 1.0, block_n=GROUP_BLOCK),
-        shape((4, _padded(GROUP_BLOCK)), jnp.float32),
     )
 
 
