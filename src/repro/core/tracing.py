@@ -36,8 +36,8 @@ _OFF = contextlib.nullcontext()
 @dataclasses.dataclass
 class Tick:
     """One ``QueryServer.step``: the requests it took, the kernel slots it
-    filled, how long they had queued, and its span on the host clock
-    (``time.perf_counter``)."""
+    filled and the MXU passes they took, how long they had queued, and its
+    span on the host clock (``time.perf_counter``)."""
 
     tick: int
     start_s: float
@@ -45,6 +45,7 @@ class Tick:
     query: str = ""
     requests: int = 0
     slots: int = 0  # kernel slots of its call, padding included
+    passes: int = 0  # one-hot MXU passes a block of its call: slots over programs a pass
     queue_wait_s: float = 0.0  # summed over its requests: admission -> start_s
     args: dict[str, Any] = dataclasses.field(default_factory=dict)  # of its spans
 

@@ -19,22 +19,30 @@ This kernel makes any such query ONE pass over a ``[C, N]`` column block:
     derived ``disc_price = price * (1 - discount)`` and
     ``charge = disc_price * (1 + tax)`` are term products evaluated
     in-register, never materialized in HBM;
-  * per-group accumulation for G dictionary-coded groups lands in a
-    ``[G, LANES]`` slot of a VMEM-resident output via a one-hot MXU matmul
-    (``onehot[G, bn] @ vals[bn, A+1]``); TPU grids iterate sequentially, so
-    the running accumulator across blocks is safe;
+  * per-group accumulation for G dictionary-coded groups goes through the
+    MXU: a one-hot group membership times the masked per-row values
+    (``onehot[G, bn] @ vals[bn, A+1]``) at full f32 precision; TPU grids
+    iterate sequentially, so the running accumulator across blocks, a
+    VMEM-resident output, is safe;
   * the constants of both programs carry a leading program dimension B, so
     one pass over the columns serves B requests of the same query shape
-    (scan sharing); a single query is ``B = 1``.
+    (scan sharing); a single query is ``B = 1``.  A grid step runs
+    ``P = programs_per_pass(B, A)`` programs over its block in ONE dot:
+    each predicate's columns and each term's column transform are read
+    once, each program applies its own constants, and the programs' masked
+    values ``[P * (A + 1), bn]``, stacked, meet one shared one-hot
+    ``[Gp, bn]`` (G padded to 8).  P is as large as keeps the stack within
+    one 128-row MXU tile: the served queries at ``B <= 8`` take one pass,
+    ``B = 1`` one program a step.
 
 Padding contract: rows whose key is outside ``[0, num_groups)`` (the ops
 wrapper pads with -1) match no one-hot row and therefore contribute to no
 group, regardless of what the predicate program evaluates to on padded
 junk — padding correctness does not depend on the program.
 
-Output layout: ``out[b, g, a]`` = sum of aggregate ``a`` over the rows of
-group ``g`` that program ``b`` selects, for ``a < A``; ``out[b, g, A]`` =
-that masked row count.
+Output layout of :func:`group_filter_agg`: ``out[b, g, a]`` = sum of
+aggregate ``a`` over the rows of group ``g`` that program ``b`` selects,
+for ``a < A``; ``out[b, g, A]`` = that masked row count.
 """
 from __future__ import annotations
 
@@ -47,6 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+SUBLANES = 8
+MXU_TILE = 128  # value rows one one-hot dot may take
 
 # Predicate opcodes (pred_ops[k, 0]).
 PRED_RANGE = 0  # lo <= cols[a] < hi
@@ -135,44 +145,53 @@ def encode_aggregates(aggs) -> tuple[np.ndarray, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
-def _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds: int, prog):
-    """Row mask [1, bn] as f32 0/1 from program ``prog``'s predicates (ANDed).
+def programs_per_pass(num_progs: int, num_aggs: int) -> int:
+    """Programs one grid step folds into a single one-hot dot: as many as
+    keep their stacked value rows ``P * (A + 1)`` within one MXU tile."""
+    return min(num_progs, MXU_TILE // (num_aggs + 1))
+
+
+def _eval_masks(pred_ops_ref, pred_consts_ref, cols_ref, num_preds: int, progs):
+    """Row masks [1, bn] as f32 0/1, one per program in ``progs``, from its
+    predicates (ANDed).  Each predicate's columns and column compare are
+    read once for all of them.
 
     Each predicate's two candidate outcomes are converted to f32 before the
     opcode select: Mosaic cannot select between boolean vectors.
     """
-    bn = cols_ref.shape[1]
-    mask = jnp.ones((1, bn), jnp.float32)
+    masks = [None] * len(progs)
     for k in range(num_preds):
         kind = pred_ops_ref[k, 0]
-        a = pred_ops_ref[k, 1]
-        b = pred_ops_ref[k, 2]
-        lo = pred_consts_ref[prog, k, 0]
-        hi = pred_consts_ref[prog, k, 1]
-        ca = cols_ref[pl.ds(a, 1), :]
-        cb = cols_ref[pl.ds(b, 1), :]
-        in_range = ((ca >= lo) & (ca < hi)).astype(jnp.float32)
+        ca = cols_ref[pl.ds(pred_ops_ref[k, 1], 1), :]
+        cb = cols_ref[pl.ds(pred_ops_ref[k, 2], 1), :]
         less = (ca < cb).astype(jnp.float32)
-        mask = mask * jnp.where(kind == PRED_RANGE, in_range, less)
-    return mask
+        for j, prog in enumerate(progs):
+            lo = pred_consts_ref[prog, k, 0]
+            hi = pred_consts_ref[prog, k, 1]
+            in_range = ((ca >= lo) & (ca < hi)).astype(jnp.float32)
+            m = jnp.where(kind == PRED_RANGE, in_range, less)
+            masks[j] = m if masks[j] is None else masks[j] * m
+    return masks
 
 
-def _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a: int, prog):
-    """Per-row value [1, bn] of aggregate ``a``: the product of its terms."""
-    bn = cols_ref.shape[1]
-    val = jnp.ones((1, bn), jnp.float32)
+def _eval_values(agg_ops_ref, agg_consts_ref, cols_ref, a: int, progs):
+    """Per-row values [1, bn] of aggregate ``a``, one per program in
+    ``progs``: the product of its terms.  Each term's column transform is
+    built once for all of them; its ``le``/``gt`` indicator comes from each
+    program's constants."""
+    vals = [None] * len(progs)
     for t in range(MAX_TERMS):
         mode = agg_ops_ref[a, 2 * t]
-        col = agg_ops_ref[a, 2 * t + 1]
-        const = agg_consts_ref[prog, a, t]
-        c = cols_ref[pl.ds(col, 1), :].astype(jnp.float32)
+        c = cols_ref[pl.ds(agg_ops_ref[a, 2 * t + 1], 1), :].astype(jnp.float32)
         term = jnp.where(mode == TERM_COL, c, 1.0)
         term = jnp.where(mode == TERM_ONE_MINUS, 1.0 - c, term)
         term = jnp.where(mode == TERM_ONE_PLUS, 1.0 + c, term)
-        term = jnp.where(mode == TERM_LE, (c <= const).astype(jnp.float32), term)
-        term = jnp.where(mode == TERM_GT, (c > const).astype(jnp.float32), term)
-        val = val * term
-    return val
+        for j, prog in enumerate(progs):
+            const = agg_consts_ref[prog, a, t]
+            tj = jnp.where(mode == TERM_LE, (c <= const).astype(jnp.float32), term)
+            tj = jnp.where(mode == TERM_GT, (c > const).astype(jnp.float32), tj)
+            vals[j] = tj if vals[j] is None else vals[j] * tj
+    return vals
 
 
 def _kernel(
@@ -182,46 +201,51 @@ def _kernel(
     agg_consts_ref,  # [B, A, MAX_TERMS] SMEM: per-program term constants
     cols_ref,
     keys_ref,
-    out_ref,  # the whole [B, G, LANES] output, resident for the entire grid
+    out_ref,  # the whole [passes, Gp, LANES] output, resident for the entire grid
     *,
-    num_groups: int,
     num_preds: int,
     num_aggs: int,
+    num_progs: int,
+    per_pass: int,
 ):
     i = pl.program_id(0)  # data block (outer grid dim)
-    prog = pl.program_id(1)  # program slot (inner grid dim)
+    p = pl.program_id(1)  # pass (inner grid dim)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[prog] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+        out_ref[p] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
-    bn = cols_ref.shape[1]
-    maskf = _eval_mask(pred_ops_ref, pred_consts_ref, cols_ref, num_preds, prog)
+    # The pass's programs; a short last pass repeats the last one.
+    progs = [jnp.minimum(p * per_pass + j, num_progs - 1) for j in range(per_pass)]
+    masks = _eval_masks(pred_ops_ref, pred_consts_ref, cols_ref, num_preds, progs)
 
-    # Masked one-hot group membership [G, bn]; padded rows carry key -1 and
-    # match no row of the iota, so they vanish from every group.
+    # Per program, its masked aggregate values and then its mask, which the
+    # dot turns into the masked count: [A + 1, bn] a program, stacked.
+    rows = [[] for _ in progs]
+    for a in range(num_aggs):
+        vals = _eval_values(agg_ops_ref, agg_consts_ref, cols_ref, a, progs)
+        for r, v, m in zip(rows, vals, masks):
+            r.append(v * m)
+    for r, m in zip(rows, masks):
+        r.append(m)
+    stacked = jnp.concatenate([v for r in rows for v in r], axis=0)
+
+    # One-hot group membership [Gp, bn] (G padded to 8), shared by every
+    # program; padded rows carry key -1 and match no row of the iota, so
+    # they vanish from every group.
     keys = keys_ref[...]  # [1, bn] i32
-    group_ids = jax.lax.broadcasted_iota(jnp.int32, (num_groups, bn), 0)
-    onehot = (group_ids == keys).astype(jnp.float32) * maskf
+    group_ids = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[1], keys.shape[1]), 0)
+    onehot = (group_ids == keys).astype(jnp.float32)
 
-    # Per-row aggregate values [A + 1, bn]; the trailing row of ones becomes
-    # the per-group masked count through the same matmul.
-    rows = [
-        _eval_terms(agg_ops_ref, agg_consts_ref, cols_ref, a, prog)
-        for a in range(num_aggs)
-    ]
-    rows.append(jnp.ones((1, bn), jnp.float32))
-    vals = jnp.concatenate(rows, axis=0)
-
-    # [G, bn] x [A+1, bn]^T -> [G, A+1]: the whole grouped aggregation for
-    # this block in one MXU pass at full f32 precision, accumulated into
-    # this program's slot of the resident output.
+    # [Gp, bn] x [P (A+1), bn]^T -> [Gp, P (A+1)]: the grouped aggregation
+    # of every program of the pass in one MXU pass at full f32 precision;
+    # program j's sums and count are columns [j (A+1), (j+1) (A+1)).
     upd = jax.lax.dot_general(
-        onehot, vals, (((1,), (1,)), ((), ())),
+        onehot, stacked, (((1,), (1,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
-    out_ref[prog] += jnp.pad(upd, ((0, 0), (0, LANES - (num_aggs + 1))))
+    out_ref[p] += jnp.pad(upd, ((0, 0), (0, LANES - upd.shape[1])))
 
 
 def group_filter_agg(
@@ -241,13 +265,16 @@ def group_filter_agg(
     All programs share one opcode structure but carry their own constants,
     so N concurrent q6 requests with different predicate bounds become one
     kernel invocation (scan sharing); a single query is the ``B = 1`` case.
-    The grid is ``(blocks, B)`` with the program slot innermost: the
-    ``[C, bn]`` column block's index map is constant across the inner
-    dimension, so the block stays in VMEM while every program runs over it.
-    The ``[B, G, LANES]`` output has a constant index map, so it stays
-    resident in VMEM for the whole grid and is written back once at the
-    end; each program accumulates into its own slot, visiting the data
-    blocks in order, as the ``B = 1`` call does.
+    The grid is ``(blocks, passes)``.  Each grid step runs
+    ``P = programs_per_pass(B, A)`` programs over the ``[C, bn]`` column
+    block: each program's mask and masked values, stacked, meet one one-hot
+    group membership in one dot.  The served queries at ``B <= 8`` take one
+    pass; ``B = 1`` is one program a step.  A batch wider than P takes
+    several passes, whose column block stays in VMEM across the inner grid
+    dimension.  The ``[passes, Gp, LANES]`` output has a constant index
+    map, so it stays resident in VMEM for the whole grid and is written
+    back once at the end; each program accumulates into its own columns of
+    it, visiting the data blocks in order, as the ``B = 1`` call does.
 
     Returns ``[B, num_groups, A + 1]`` f32: per-group aggregate sums, then
     the masked count.
@@ -261,29 +288,35 @@ def group_filter_agg(
     num_aggs = agg_ops.shape[0]
     assert num_aggs + 1 <= LANES, num_aggs
     assert num_groups >= 1
+    per_pass = programs_per_pass(num_progs, num_aggs)
+    passes = -(-num_progs // per_pass)
+    rows = -(-num_groups // SUBLANES) * SUBLANES
 
     out = pl.pallas_call(
         functools.partial(
             _kernel,
-            num_groups=num_groups,
             num_preds=num_preds,
             num_aggs=num_aggs,
+            num_progs=num_progs,
+            per_pass=per_pass,
         ),
-        grid=(n // bn, num_progs),
+        grid=(n // bn, passes),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((cols.shape[0], bn), lambda i, b: (0, i)),
-            pl.BlockSpec((1, bn), lambda i, b: (0, i)),
+            pl.BlockSpec((cols.shape[0], bn), lambda i, p: (0, i)),
+            pl.BlockSpec((1, bn), lambda i, p: (0, i)),
         ],
-        out_specs=pl.BlockSpec((num_progs, num_groups, LANES), lambda i, b: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_progs, num_groups, LANES), jnp.float32),
+        out_specs=pl.BlockSpec((passes, rows, LANES), lambda i, p: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((passes, rows, LANES), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
         name="group_filter_agg",
     )(pred_ops, pred_consts, agg_ops, agg_consts, cols, keys)
-    return out[:, :, : num_aggs + 1]
+    width = num_aggs + 1
+    out = out[:, :num_groups, : per_pass * width].reshape(passes, num_groups, per_pass, width)
+    return out.transpose(0, 2, 1, 3).reshape(passes * per_pass, num_groups, width)[:num_progs]

@@ -29,6 +29,7 @@ from typing import Any, Callable
 
 from repro.core import tracing
 from repro.engine import queries as queries_mod
+from repro.kernels.group_filter_agg import programs_per_pass
 from repro.runtime.loadgen import sample_params
 from repro.runtime.requests import QueryCompletion, QueryRequest, RequestQueue
 
@@ -76,8 +77,9 @@ class QueryServer:
 
     Each ``step`` is a tick of :data:`repro.core.tracing.RECORDER`: its
     phases are ``serve.*`` spans, and its record counts the requests, the
-    padded kernel slots and how long the requests queued.  The first server
-    of a process turns garbage collections into ``serve.gc`` spans.
+    padded kernel slots, the kernel's MXU passes a block and how long the
+    requests queued.  The first server of a process turns garbage
+    collections into ``serve.gc`` spans.
     """
 
     def __init__(
@@ -120,12 +122,14 @@ class QueryServer:
 
     def _execute(self, batch: list[QueryRequest]) -> list[dict[str, Any]]:
         """One kernel pass for ``batch`` (padded to a power of two); its
-        slots are counted in the open tick.  The results are on the host:
-        the plan functions wait for the kernel in ``serve.wait``."""
+        slots and the kernel's MXU passes a block are counted in the open
+        tick.  The results are on the host: the plan functions wait for the
+        kernel in ``serve.wait``."""
         plan = self.plans[batch[0].query]
         self.kernel_calls += 1
         tick = tracing.RECORDER.current()
         tick.slots = _pow2_at_least(len(batch))
+        tick.passes = -(-tick.slots // programs_per_pass(tick.slots, plan.agg_ops.shape[0]))
         padded = [r.params for r in batch]
         padded += [batch[0].params] * (tick.slots - len(batch))
         results = queries_mod.fused_query_batch(plan, padded, use_pallas=self.use_pallas)

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.kernels import group_filter_agg as gfa_kernel
 from repro.kernels import ops, ref
 from repro.kernels.group_filter_agg import encode_aggregates, encode_predicates
 
@@ -238,6 +239,78 @@ def test_group_filter_agg_property(n, num_groups, lo, width):
     np.testing.assert_array_equal(np.asarray(exp[:, -1]), np.asarray(out[:, -1]))
     for g in range(num_groups):
         assert int(out[g, -1]) == int(((k == g) & m).sum())
+
+
+def _batch_programs(shape, num_progs):
+    """``num_progs`` programs of one shape, each with its own predicate
+    bounds and ``le``/``gt`` constants: (pred_ops, pred_consts [B, K, 2],
+    agg_ops, agg_consts [B, A, MAX_TERMS], numpy mask of program b)."""
+    if shape == "q12":  # q12's kernel shape: conditional counts behind 3 predicates
+        preds = lambda b: [("range", 0, 0.05 * b, 0.6 + 0.04 * b), ("lt", 1, 2), ("lt", 3, 2)]
+        aggs = lambda b: [[("le", 4, 0.2 + 0.07 * b)], [("gt", 4, 0.2 + 0.07 * b)]]
+        masks = lambda c, b: (c[0] >= 0.05 * b) & (c[0] < 0.6 + 0.04 * b) & (c[1] < c[2]) & (c[3] < c[2])
+    else:  # _gfa_case's program: column, derived and le/gt terms; "wide" repeats it 8 times
+        preds = lambda b: [("range", 0, 0.03 * b, 0.5 + 0.05 * b), ("lt", 1, 2)]
+        aggs = lambda b: [
+            [("col", 3)],
+            [("col", 3), ("one_minus", 4)],
+            [("col", 3), ("one_minus", 4), ("one_plus", 2)],
+            [("le", 1, 0.3 + 0.06 * b)],
+            [("gt", 1, 0.6 - 0.05 * b), ("col", 2)],
+        ] * (8 if shape == "wide" else 1)
+        masks = lambda c, b: (c[0] >= 0.03 * b) & (c[0] < 0.5 + 0.05 * b) & (c[1] < c[2])
+    encoded = [(*encode_predicates(preds(b)), *encode_aggregates(aggs(b))) for b in range(num_progs)]
+    po, _, ao, _ = encoded[0]
+    pc = np.stack([e[1] for e in encoded])
+    ac = np.stack([e[3] for e in encoded])
+    return po, pc, ao, ac, masks
+
+
+@pytest.mark.parametrize("num_progs", [1, 2, 3, 4, 8])
+@pytest.mark.parametrize(
+    "shape,num_groups",
+    [("gfa", 1), ("gfa", 6), ("q12", 7), ("gfa", 128), ("wide", 6)],
+    ids=["one-group", "six-groups", "q12", "groups128", "40-aggs-three-a-pass"],
+)
+def test_group_filter_agg_batch_slots(shape, num_groups, num_progs):
+    """Every slot of a B-program call equals its own B = 1 call (counts
+    exactly, sums to within one block's order of additions), the oracle and
+    plain numpy, whether the programs share one MXU pass or not; each
+    slot's own constants make a slot read from the wrong columns show."""
+    n = 9000  # three blocks of 4096, the last one ragged
+    cols = jax.random.uniform(jax.random.fold_in(KEY, 40 + num_groups), (5, n), jnp.float32)
+    keys = jax.random.randint(jax.random.fold_in(KEY, 41), (n,), 0, num_groups)
+    po, pc, ao, ac, masks = _batch_programs(shape, num_progs)
+    out = np.asarray(ops.group_filter_agg_multi(cols, keys, po, pc, ao, ac,
+                                                num_groups=num_groups, block_n=4096))
+    assert out.shape == (num_progs, num_groups, ao.shape[0] + 1)
+    c, k = np.asarray(cols), np.asarray(keys)
+    for b in range(num_progs):
+        alone = np.asarray(ops.group_filter_agg_multi(cols, keys, po, pc[b : b + 1], ao, ac[b : b + 1],
+                                                      num_groups=num_groups, block_n=4096))[0]
+        np.testing.assert_array_equal(out[b, :, -1], alone[:, -1])
+        np.testing.assert_allclose(out[b], alone, rtol=2e-5, atol=0)
+        exp = np.asarray(ref.group_filter_agg_ref(cols, keys, po, pc[b], ao, ac[b], num_groups))
+        np.testing.assert_array_equal(out[b, :, -1], exp[:, -1])
+        np.testing.assert_allclose(out[b], exp, rtol=2e-5, atol=1e-3)
+        m = masks(c, b)
+        np.testing.assert_array_equal(out[b, :, -1], [((k == g) & m).sum() for g in range(num_groups)])
+
+
+@pytest.mark.parametrize(
+    "num_progs,num_aggs",
+    [(1, 1), (8, 1), (8, 5), (8, 2), (2, 5), (4, 2), (3, 5), (16, 5), (64, 1), (100, 1),
+     (8, 40), (3, 40), (8, 127), (1, 127)],
+)
+def test_programs_per_pass(num_progs, num_aggs):
+    """As many programs as keep their stacked value rows within one 128-row
+    MXU tile; the served shapes at B <= 8 take one pass."""
+    p = gfa_kernel.programs_per_pass(num_progs, num_aggs)
+    assert 1 <= p <= num_progs
+    assert p * (num_aggs + 1) <= 128
+    assert p == num_progs or (p + 1) * (num_aggs + 1) > 128
+    if num_aggs in (5, 1, 2) and num_progs <= 8:  # q1, q6, q12
+        assert p == num_progs
 
 
 @pytest.mark.parametrize("all_pass", [True, False])

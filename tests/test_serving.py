@@ -314,6 +314,21 @@ def test_a_one_request_tick_is_a_one_slot_batch(plans, monkeypatch):
     _assert_same_result(batch(plans["q1"], [req.params])[0], done.result, "q1")
 
 
+@pytest.mark.parametrize("size", [8, 1])
+def test_a_warm_q1_tick_takes_one_mxu_pass(plans, size):
+    """Eight q1 requests share one one-hot pass a block, as one does."""
+    from repro.core import tracing
+
+    server = QueryServer(plans, max_batch=8)
+    server.warmup(["q1"])
+    rng = random.Random(size)
+    for i in range(size):
+        server.submit(QueryRequest(uid=i, query="q1", params=sample_params("q1", rng)))
+    assert len(server.step()) == size
+    tick = tracing.RECORDER.ticks[-1]
+    assert (tick.slots, tick.passes) == (size, 1)
+
+
 def test_server_coalesces_only_same_query_shape(plans):
     server = QueryServer(plans, max_batch=8)
     rng = random.Random(0)

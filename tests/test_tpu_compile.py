@@ -51,12 +51,13 @@ def _assert_kernel_compiles(fn, *args):
 
 
 # (groups, columns, predicates, aggregates, programs) of the fused q6, q1
-# and q12 plans, the serving batch of eight q12 requests, and the pushdown
-# task's aggregate at the data.
+# and q12 plans, the serving batches of eight requests of each, and the
+# pushdown task's aggregate at the data.
 @pytest.mark.parametrize(
     "groups,cols,preds,aggs,progs",
-    [(1, 4, 3, 1, 1), (6, 5, 1, 5, 1), (7, 4, 3, 2, 1), (7, 4, 3, 2, 8), (1, 2, 1, 1, 1)],
-    ids=["q6", "q1", "q12", "q12-batch8", "pushdown"],
+    [(1, 4, 3, 1, 1), (6, 5, 1, 5, 1), (7, 4, 3, 2, 1), (7, 4, 3, 2, 8), (1, 2, 1, 1, 1),
+     (6, 5, 1, 5, 8), (1, 4, 3, 1, 8)],
+    ids=["q6", "q1", "q12", "q12-batch8", "pushdown", "q1-batch8", "q6-batch8"],
 )
 def test_group_filter_agg_compiles(shape, groups, cols, preds, aggs, progs):
     n = _padded(GROUP_BLOCK)

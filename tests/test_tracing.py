@@ -83,7 +83,7 @@ def test_spans_cost_nothing_without_the_profiler():
 def test_ticks_count_padded_slots(server, batch, slots):
     calls = server.kernel_calls
     tick = _serve(server, Q6[:batch])
-    assert (tick.requests, tick.slots, tick.query) == (batch, slots, "q6")
+    assert (tick.requests, tick.slots, tick.passes, tick.query) == (batch, slots, 1, "q6")
     assert tick.slots - tick.requests == slots - batch  # 5 requests: 3 padding slots
     assert server.kernel_calls == calls + 1
     assert tick.start_s <= tick.end_s
@@ -116,7 +116,7 @@ def test_an_empty_step_is_a_tick_without_requests(server):
     assert server.step() == []
     tick = tracing.RECORDER.ticks[-1]
     assert len(tracing.RECORDER.ticks) == min(n + 1, tracing.RING)
-    assert (tick.requests, tick.slots, tick.queue_wait_s) == (0, 0, 0.0)
+    assert (tick.requests, tick.slots, tick.passes, tick.queue_wait_s) == (0, 0, 0, 0.0)
 
 
 def test_a_collection_is_one_gc_record_and_one_span(server, tmp_path):
